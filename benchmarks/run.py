@@ -903,6 +903,8 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_PR10.json",
                     help="telemetry JSON path ('' disables)")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     if only and not only <= set(SECTIONS):
         ap.error(f"unknown sections: {sorted(only - set(SECTIONS))}")

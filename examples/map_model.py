@@ -14,6 +14,7 @@ a minute on one CPU core.
 import sys
 import time
 
+from repro.compile_cache import use_compile_cache
 from repro.core.api import SharedMapConfig, shared_map
 from repro.core.mapping import evaluate_J
 from repro.launch.comm_graph import default_placement, model_comm_graph
@@ -21,6 +22,7 @@ from repro.launch.mesh import physical_hierarchy
 
 
 def main(arch: str = "whisper-tiny"):
+    use_compile_cache()
     h = physical_hierarchy(False)  # 16 chips/rack x 16 racks, D = 1:10
     print(f"hierarchy {h} -> k={h.k} chips")
 

@@ -7,6 +7,7 @@ co-activated experts on nearby chips cuts cross-rack/pod all-to-all volume.
 """
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import graph as G
 from repro.core.api import SharedMapConfig, shared_map
 from repro.core.hierarchy import Hierarchy
@@ -14,6 +15,7 @@ from repro.core.mapping import evaluate_J
 
 
 def main():
+    use_compile_cache()
     rng = np.random.default_rng(0)
     E = 64  # moonshot-style expert count
     # synthetic co-activation: block-structured (experts specialize by topic)

@@ -4,6 +4,7 @@
 """
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import graph as G
 from repro.core.api import SharedMapConfig, shared_map
 from repro.core.baselines import identity_mapping, random_mapping
@@ -12,6 +13,7 @@ from repro.core.mapping import evaluate_J
 
 
 def main():
+    use_compile_cache()
     # A sparse communication graph: 6,000 tasks from a random-geometric
     # pattern (typical of domain-decomposed scientific codes).
     g = G.gen_rgg(6_000, seed=0)

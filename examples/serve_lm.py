@@ -5,12 +5,14 @@
 import numpy as np
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.registry import get_smoke_config
 from repro.models import model as M
 from repro.serve.engine import Engine
 
 
 def main():
+    use_compile_cache()
     cfg = get_smoke_config("mixtral-8x22b")  # MoE + sliding window
     params = M.init_fn(cfg, jax.random.PRNGKey(0))
     eng = Engine(cfg, params, max_len=96)

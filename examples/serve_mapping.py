@@ -14,6 +14,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import graph as G
 from repro.core.api import SharedMapConfig, shared_map, shared_map_direct
 from repro.core.hierarchy import Hierarchy
@@ -28,6 +29,7 @@ async def traffic(svc: MappingService, gs, h, cfg):
 
 
 def main():
+    use_compile_cache()
     h = Hierarchy(a=(2, 2, 2, 2), d=(1.0, 5.0, 10.0, 100.0))  # 16 PEs
     cfg = SharedMapConfig(preset="fast")
     gs = [G.gen_rgg(64, seed=100 + i) for i in range(8)]

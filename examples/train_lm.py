@@ -7,10 +7,12 @@ driver lowers the full 72B configs in the multi-pod dry-run).
 import argparse
 import sys
 
+from repro.compile_cache import use_compile_cache
 from repro.launch.train import main as train_main
 
 
 def run():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

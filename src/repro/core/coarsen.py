@@ -32,12 +32,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .graph import Graph, default_ell_deg, edge_mask, ell_adjacency, vertex_mask
 from ..kernels import ops as kops
 
-_HASH_A = jnp.uint32(2654435761)
-_HASH_B = jnp.uint32(40503)
+# numpy scalars: a jnp constant here would start a JAX backend at import
+_HASH_A = np.uint32(2654435761)
+_HASH_B = np.uint32(40503)
 
 # per-round salt stride: any odd constant; mixed into the edge jitter so
 # round r+1 re-rolls every tie-break (see hem_match round fix below)

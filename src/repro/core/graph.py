@@ -101,12 +101,15 @@ def assemble_padded(
     n: int,
     N: int,
     M: int,
+    host: bool = False,
 ) -> Graph:
     """Assemble a device `Graph` from REAL (unpadded) host arrays.
 
     ``rows`` must be sorted ascending; one host->device transfer per field.
     This is the single construction path shared by `from_edges`,
-    `pad_graph` and the multisection subgraph extractor.
+    `pad_graph` and the multisection subgraph extractor. ``host=True``
+    keeps the fields as numpy arrays and starts no JAX backend (a process
+    that only forwards graphs to worker processes).
     """
     m = int(np.asarray(rows).shape[0])
     check_i32_range(max(n, N), max(m, M))
@@ -120,15 +123,10 @@ def assemble_padded(
     w[:m] = ewgt
     vw = np.zeros(N, np.float32)
     vw[:n] = vwgt
-    return Graph(
-        vwgt=jnp.asarray(vw),
-        rows=jnp.asarray(r),
-        cols=jnp.asarray(c),
-        ewgt=jnp.asarray(w),
-        indptr=jnp.asarray(padded_csr_indptr(r, m, N), jnp.int32),
-        n=jnp.asarray(n, jnp.int32),
-        m=jnp.asarray(m, jnp.int32),
-    )
+    g = Graph(vwgt=vw, rows=r, cols=c, ewgt=w,
+              indptr=padded_csr_indptr(r, m, N).astype(np.int32),
+              n=np.int32(n), m=np.int32(m))
+    return g if host else Graph(*map(jnp.asarray, g))
 
 
 def from_edges(
@@ -139,11 +137,13 @@ def from_edges(
     vwgt: np.ndarray | None = None,
     N: int | None = None,
     M: int | None = None,
+    host: bool = False,
 ) -> Graph:
     """Build a padded CSR `Graph` from an undirected edge list (host-side).
 
     ``u, v`` are endpoints of undirected edges (each listed once); weights
     default to 1. ``N``/``M`` give the padded sizes (default: exact fit).
+    ``host`` as in :func:`assemble_padded`.
     """
     u = np.asarray(u, np.int64)
     v = np.asarray(v, np.int64)
@@ -161,7 +161,8 @@ def from_edges(
     M = int(M if M is not None else max(m, 1))
 
     order = np.argsort(du, kind="stable")
-    return assemble_padded(vwgt_np, du[order], dv[order], dw[order], n, N, M)
+    return assemble_padded(vwgt_np, du[order], dv[order], dw[order], n, N, M,
+                           host=host)
 
 
 def edge_mask(g: Graph) -> jax.Array:
@@ -195,7 +196,8 @@ def block_weights(g: Graph, part: jax.Array, k: int) -> jax.Array:
 # All host-side numpy, seeded, deterministic.
 # ---------------------------------------------------------------------------
 
-def gen_rgg(n: int, seed: int = 0, radius_scale: float = 0.55) -> Graph:
+def gen_rgg(n: int, seed: int = 0, radius_scale: float = 0.55,
+            host: bool = False) -> Graph:
     """Random geometric graph in the unit square (paper: rgg23/rgg24)."""
     rng = np.random.default_rng(seed)
     pts = rng.random((n, 2))
@@ -232,10 +234,10 @@ def gen_rgg(n: int, seed: int = 0, radius_scale: float = 0.55) -> Graph:
             vs.append(vv[keep])
     u = np.concatenate(us) if us else np.zeros(0, np.int64)
     v = np.concatenate(vs) if vs else np.zeros(0, np.int64)
-    return from_edges(n, u, v)
+    return from_edges(n, u, v, host=host)
 
 
-def gen_grid(side: int, diag: bool = True) -> Graph:
+def gen_grid(side: int, diag: bool = True, host: bool = False) -> Graph:
     """Triangulated grid — a Delaunay-triangulation stand-in (del23/del24)."""
     n = side * side
     idx = np.arange(n).reshape(side, side)
@@ -244,7 +246,7 @@ def gen_grid(side: int, diag: bool = True) -> Graph:
     if diag:
         us.append(idx[:-1, :-1].ravel())
         vs.append(idx[1:, 1:].ravel())
-    return from_edges(n, np.concatenate(us), np.concatenate(vs))
+    return from_edges(n, np.concatenate(us), np.concatenate(vs), host=host)
 
 
 def gen_road(n: int, seed: int = 0) -> Graph:

@@ -71,9 +71,11 @@ def _vhash(n: int, salt) -> jax.Array:
     return x ^ (x >> 12)
 
 
-def resolve_backend(backend: str) -> str:
+def resolve_backend(backend: str, platform: str | None = None) -> str:
+    """``auto`` -> ``ell`` or ``xla`` (module docstring). ``platform``
+    names the JAX platform the mapping runs on; None asks this process."""
     if backend == "auto":
-        return "ell" if kops.kernel_backend() != "xla" else "xla"
+        return "ell" if kops.kernel_backend(platform) != "xla" else "xla"
     if backend not in ("ell", "xla"):
         raise ValueError(f"unknown refine backend {backend!r}")
     return backend

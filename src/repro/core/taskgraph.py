@@ -205,19 +205,23 @@ class TaskGraph:
             hs.update(a.tobytes())
         return hs.digest()
 
-    def to_graph(self, N: int | None = None, M: int | None = None) -> G.Graph:
+    def to_graph(self, N: int | None = None, M: int | None = None,
+                 host: bool = False) -> G.Graph:
         """The canonical padded-CSR :class:`core.graph.Graph` (cached for
-        the default padding). A pure function of the canonical arrays, so
-        equal fingerprints give bitwise-equal CSR graphs."""
-        if N is None and M is None:
+        the default padding on the device). A pure function of the
+        canonical arrays, so equal fingerprints give bitwise-equal CSR
+        graphs. ``host=True`` keeps the arrays in numpy
+        (:func:`core.graph.assemble_padded`)."""
+        memo = N is None and M is None and not host
+        if memo:
             cached = _GRAPH_MEMO.get(id(self))
             if cached is not None and cached[0] is self:
                 return cached[1]
         g = G.from_edges(self.n, self.u.astype(np.int64),
                          self.v.astype(np.int64),
                          self.w.astype(np.float64), vwgt=self.vwgt,
-                         N=N, M=M)
-        if N is None and M is None:
+                         N=N, M=M, host=host)
+        if memo:
             _GRAPH_MEMO[id(self)] = (self, g)
         return g
 

@@ -3,31 +3,32 @@
 Coarsening is the last multilevel stage without a kernel path: the seed's
 ``coarsen.hem_match`` runs two ``segment_max``/``segment_min`` scatter
 passes per round over the ``[M]`` edge arrays, and ``coarsen.contract``
-two stable argsorts. The TPU-native restatement works row-wise over the
-padded ``[N, DEG]`` ELL adjacency (same layout as ``lp_gain``):
+two stable argsorts. The TPU-native restatement works per vertex over the
+transposed ELL adjacency ``[DEG, N]`` (vertices on lanes, as in
+``lp_gain``):
 
-``hem_propose`` — one program instance scans ``TILE_V`` rows: load the
-    ``adj/adw/jit`` tiles, gather matched flags from the VMEM-resident
-    ``matched`` vector, take the per-row max jittered score and the
-    smallest-id tie-break. Replaces both segment passes with a single
-    streaming pass, no scatters.
+``hem_propose`` — XLA gathers the matched flags of every neighbour slot
+    (``matched[adj]``); one program instance then scans ``TILE_V``
+    vertices: the per-vertex max jittered score and the smallest-id
+    tie-break, written as a lane-dense ``[1, TILE_V]`` row. Replaces both
+    segment passes with a single streaming pass, no scatters.
 
 ``contract_edges`` — one program instance merges ``TILE_C`` coarse rows:
-    each row holds the ``2*DEG`` coarse-mapped neighbour candidates of its
-    (<= 2) fine members; a fixed-order compare/accumulate chain dedups ids
-    and sums weights. Fully tiled — NO resident vectors — so it scales
-    with HBM, not VMEM.
+    each column holds the ``2*DEG`` coarse-mapped neighbour candidates of
+    its (<= 2) fine members; a fixed-order compare/accumulate chain dedups
+    ids and sums weights. The distinct count per row is an integer sum
+    left to XLA. Fully tiled — NO resident vectors — so it scales with
+    HBM, not VMEM.
 
 Both kernel bodies execute the SAME jnp code as the oracles
-(kernels/ref.py: ``hem_row_scan`` / ``merge_dedup_rows``), so pallas /
+(kernels/ref.py: ``hem_scan_t`` / ``merge_dedup_t``), so pallas /
 interpret / xla agree BITWISE: score math is elementwise f32, reductions
 are max/min/int-only, and weight totals are a fixed add chain XLA never
-reassociates. The device-resident multisection's shadow-verification twin
-(PR 8) depends on this; tested in tests/test_coarsen_kernels.py.
+reassociates. Tested in tests/test_coarsen_kernels.py; compiled for the
+TPU in tests/test_tpu_compile.py.
 
-VMEM budget per instance: hem_propose holds ``matched [Np] i32`` resident
-(+3 row tiles), so Np <~ 3M rows on a 16 MB core; contract_edges holds
-only its tiles (~``TILE_C * 2*DEG * 4 * 4`` bytes). See DESIGN.md §13.
+VMEM per instance: hem_propose holds four ``[DEG, TILE_V]`` tiles;
+contract_edges about ``TILE_C * 2*DEG * 4 * 4`` bytes. See DESIGN.md §13.
 """
 from __future__ import annotations
 
@@ -39,92 +40,72 @@ from jax.experimental import pallas as pl
 
 from . import ref
 
-TILE_V = 256   # hem_propose rows per program instance (see lp_gain.TILE_V)
-TILE_C = 256   # contract_edges rows per program instance
+TILE_V = 256   # hem_propose vertices (lanes) per program instance
+TILE_C = 256   # contract_edges rows (lanes) per program instance
 
 
-def _hem_propose_kernel(adj_ref, adw_ref, jit_ref, matched_ref, prop_ref,
+def _hem_propose_kernel(adj_ref, adw_ref, jit_ref, nbm_ref, own_ref, prop_ref,
                         *, n_ids: int):
-    i = pl.program_id(0)
-    adj = adj_ref[...]            # [TILE_V, DEG] i32
-    adw = adw_ref[...]            # [TILE_V, DEG] f32
-    jit = jit_ref[...]            # [TILE_V, DEG] f32
-    matched = matched_ref[...]    # [Np] i32 (resident; padded rows = 1)
-    T = adj.shape[0]
-    u = i * T + jax.lax.broadcasted_iota(jnp.int32, (T, 1), 0)[:, 0]
-    prop_ref[...] = ref.hem_row_scan(adj, adw, jit, matched, u, n_ids)
+    T = adj_ref.shape[1]
+    u = pl.program_id(0) * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+    prop_ref[...] = ref.hem_scan_t(adj_ref[...], adw_ref[...], jit_ref[...],
+                                   nbm_ref[...], own_ref[...], u, n_ids)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def hem_propose_pallas(adj: jax.Array, adw: jax.Array, jit: jax.Array,
-                       matched: jax.Array, interpret: bool = True) -> jax.Array:
-    """Per-row HEM proposal over ELL adjacency. Returns [N] i32 (N = none).
+                       matched: jax.Array, *, interpret: bool) -> jax.Array:
+    """Per-vertex HEM proposal over ELL adjacency. Returns [N] i32 (N = none).
 
-    ``matched`` is the [N] 0/1 i32 matched vector; padding rows must be
-    matched (the wrapper pads with 1 so tile-pad rows propose nothing).
+    ``matched`` is the [N] 0/1 i32 matched vector; tile-padding vertices
+    are marked matched so they propose nothing.
     """
     N, DEG = adj.shape
-    Np = ((N + TILE_V - 1) // TILE_V) * TILE_V
-    padv = Np - N
-    adj_p = jnp.pad(adj, ((0, padv), (0, 0)), constant_values=N)
-    adw_p = jnp.pad(adw, ((0, padv), (0, 0)))
-    jit_p = jnp.pad(jit, ((0, padv), (0, 0)))
-    mat_p = jnp.pad(matched, (0, padv), constant_values=1)
+    Np = -(-N // TILE_V) * TILE_V
+    padc = lambda a, v=0: jnp.pad(a, ((0, 0), (0, Np - N)), constant_values=v)
+    row = pl.BlockSpec((1, TILE_V), lambda i: (0, i))
+    tile = pl.BlockSpec((DEG, TILE_V), lambda i: (0, i))
     prop = pl.pallas_call(
         functools.partial(_hem_propose_kernel, n_ids=N),
         grid=(Np // TILE_V,),
-        in_specs=[
-            pl.BlockSpec((TILE_V, DEG), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_V, DEG), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_V, DEG), lambda i: (i, 0)),
-            pl.BlockSpec((Np,), lambda i: (0,)),          # matched resident
-        ],
-        out_specs=pl.BlockSpec((TILE_V,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Np,), jnp.int32),
+        in_specs=[tile, tile, tile, tile, row],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((1, Np), jnp.int32),
         interpret=interpret,
-    )(adj_p, adw_p, jit_p, mat_p)
-    return prop[:N]
+    )(padc(adj.T, N), padc(adw.T), padc(jit.T),
+      padc(ref.neighbour_matched_t(adj, matched), 1),
+      padc(matched[None, :], 1))
+    return prop[0, :N]
 
 
-def _contract_edges_kernel(cand_ref, candw_ref, nbr_ref, w_ref, cnt_ref,
-                           *, sent: int):
-    nbr, w, cnt = ref.merge_dedup_rows(cand_ref[...], candw_ref[...], sent)
-    nbr_ref[...] = nbr
-    w_ref[...] = w
-    cnt_ref[...] = cnt
+def _contract_edges_kernel(cand_ref, candw_ref, nbr_ref, w_ref, *, sent: int):
+    nbr_ref[...], w_ref[...] = ref.merge_dedup_t(cand_ref[...], candw_ref[...],
+                                                 sent)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def contract_edges_pallas(cand: jax.Array, candw: jax.Array,
-                          interpret: bool = True):
+def contract_edges_pallas(cand: jax.Array, candw: jax.Array, *,
+                          interpret: bool):
     """Row-local merge/dedup/accumulate for contraction.
 
     ``cand [N, D2]`` holds coarse neighbour ids (sentinel ``N`` = invalid,
     weight 0). Returns ``(nbr [N, D2], w [N, D2], cnt [N])`` — see
-    ref.merge_dedup_rows. Fully tiled: no resident vectors.
+    ref.merge_dedup_t. Fully tiled: no resident vectors.
     """
     N, D2 = cand.shape
-    Np = ((N + TILE_C - 1) // TILE_C) * TILE_C
-    padv = Np - N
-    cand_p = jnp.pad(cand, ((0, padv), (0, 0)), constant_values=N)
-    candw_p = jnp.pad(candw, ((0, padv), (0, 0)))
-    nbr, w, cnt = pl.pallas_call(
+    Np = -(-N // TILE_C) * TILE_C
+    tile = pl.BlockSpec((D2, TILE_C), lambda i: (0, i))
+    nbr_t, w_t = pl.pallas_call(
         functools.partial(_contract_edges_kernel, sent=N),
         grid=(Np // TILE_C,),
-        in_specs=[
-            pl.BlockSpec((TILE_C, D2), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_C, D2), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((TILE_C, D2), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_C, D2), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_C,), lambda i: (i,)),
-        ],
+        in_specs=[tile, tile],
+        out_specs=[tile, tile],
         out_shape=[
-            jax.ShapeDtypeStruct((Np, D2), jnp.int32),
-            jax.ShapeDtypeStruct((Np, D2), candw.dtype),
-            jax.ShapeDtypeStruct((Np,), jnp.int32),
+            jax.ShapeDtypeStruct((D2, Np), jnp.int32),
+            jax.ShapeDtypeStruct((D2, Np), jnp.float32),
         ],
         interpret=interpret,
-    )(cand_p, candw_p)
-    return nbr[:N], w[:N], cnt[:N]
+    )(jnp.pad(cand.T, ((0, 0), (0, Np - N)), constant_values=N),
+      jnp.pad(candw.astype(jnp.float32).T, ((0, 0), (0, Np - N))))
+    nbr = nbr_t[:, :N].T
+    return nbr, w_t[:, :N].T, ref.distinct_counts(nbr, N)

@@ -24,10 +24,6 @@ NEG = -1e30
 BQ = 128
 BK = 128
 
-# renamed TPUCompilerParams -> CompilerParams across pallas releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                   *, scale: float, causal: bool, nk: int, window: int,
@@ -72,7 +68,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
 def flash_attention_pallas(q, k, v, causal: bool = True, window: int = 0,
-                           interpret: bool = True):
+                           *, interpret: bool):
     """q/k/v [BH, S, D] -> o [BH, S, D]. D and S padded to tile multiples."""
     BH, S, D = q.shape
     scale = D ** -0.5
@@ -102,7 +98,7 @@ def flash_attention_pallas(q, k, v, causal: bool = True, window: int = 0,
             pltpu.VMEM((BQ,), jnp.float32),
             pltpu.VMEM((BQ, Dp), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp)

@@ -1,19 +1,19 @@
-"""Pallas TPU kernel: label-propagation gain computation (refinement hot spot).
+"""Pallas TPU kernel: label-propagation connectivity (refinement hot spot).
 
 The C++ hot loop iterates each vertex's adjacency list and accumulates
-per-block connectivity in a sparse map. The TPU-native layout is ELL:
-a padded ``[N, DEG]`` neighbour matrix streamed tile-by-tile from HBM into
-VMEM. Each program instance handles ``TILE_V`` vertices:
+per-block connectivity in a sparse map. The TPU-native layout is the
+transposed ELL adjacency ``[DEG, N]``: vertices on the 128-wide lane axis,
+neighbour slots on sublanes. XLA gathers the neighbours' block ids
+(``part[adj]``) before the kernel; each program instance then streams
+``[DEG, TILE_V]`` tiles of block ids and weights from HBM into VMEM and
+accumulates the ``[k, TILE_V]`` connectivity on the VPU with one
+compare+select+add per neighbour slot (``ref.block_conn_t``, shared with
+the oracle, so the backends agree bitwise). The best alternative block and
+its gain are cheap ``[N, k]`` reductions left to XLA (``ref.best_gain``).
 
-    1. load ``adj/adw`` tiles ``[TILE_V, DEG]``,
-    2. gather neighbour block ids from the VMEM-resident ``part`` vector,
-    3. one-hot accumulate connectivity ``[TILE_V, K]`` on the VPU
-       (K-wide compare+select, no MXU),
-    4. emit per-vertex (conn, best alternative block, gain).
-
-Block shapes are (8,128)-aligned: TILE_V = 256, DEG padded to a multiple of
-128, K <= 64. VMEM footprint per instance:
-256*DEG*(4+4) + 256*K*4 bytes — e.g. DEG=128: ~0.6 MB, well inside VMEM.
+VMEM per instance: 2 * DEG * TILE_V * 4 B of input tiles plus
+k * TILE_V * 4 B of output, double-buffered — about 0.4 MB at DEG=64,
+k=16.
 """
 from __future__ import annotations
 
@@ -23,28 +23,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-TILE_V = 256
+from . import ref
+
+TILE_V = 256  # vertices (lanes) per program instance
 
 
-def _lp_gain_kernel(adj_ref, adw_ref, part_ref, pt_ref, conn_ref, best_ref, gain_ref, *, k: int):
-    N = part_ref.shape[0]
-    adj = adj_ref[...]            # [TILE_V, DEG] i32
-    adw = adw_ref[...]            # [TILE_V, DEG] f32
-    part = part_ref[...]          # [N] i32
-    nbr_part = jnp.where(adj < N, part[jnp.clip(adj, 0, N - 1)], k)  # k = "pad"
-    conn = jnp.zeros((adj.shape[0], k), jnp.float32)
-    # VPU one-hot accumulation: K compare+select passes over the DEG axis
-    for b in range(k):
-        conn = conn.at[:, b].set(jnp.sum(jnp.where(nbr_part == b, adw, 0.0), axis=1))
-    my = pt_ref[...]              # [TILE_V] i32 current blocks of this tile
-    row = jax.lax.broadcasted_iota(jnp.int32, (adj.shape[0], k), 1)
-    cur = jnp.sum(jnp.where(row == my[:, None], conn, 0.0), axis=1)
-    masked = jnp.where(row == my[:, None], -jnp.inf, conn)
-    best = jnp.argmax(masked, axis=1).astype(jnp.int32)
-    gain = jnp.max(masked, axis=1) - cur
-    conn_ref[...] = conn
-    best_ref[...] = best
-    gain_ref[...] = gain
+def _lp_conn_kernel(nbr_ref, adw_ref, conn_ref, *, k: int):
+    conn_ref[...] = ref.block_conn_t(nbr_ref[...], adw_ref[...], k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -53,36 +38,26 @@ def lp_gain_pallas(
     adw: jax.Array,   # [N, DEG] f32
     part: jax.Array,  # [N] i32
     k: int,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """Returns (conn [N,k], best [N], gain [N]) for every vertex."""
     N, DEG = adj.shape
-    Np = ((N + TILE_V - 1) // TILE_V) * TILE_V
-    padv = Np - N
-    adj_p = jnp.pad(adj, ((0, padv), (0, 0)), constant_values=N)
-    adw_p = jnp.pad(adw, ((0, padv), (0, 0)))
-    part_p = jnp.pad(part, (0, padv))
-    grid = (Np // TILE_V,)
-
-    conn, best, gain = pl.pallas_call(
-        functools.partial(_lp_gain_kernel, k=k),
-        grid=grid,
+    Np = -(-N // TILE_V) * TILE_V
+    nbr_t = jnp.pad(ref.neighbour_blocks_t(adj, part, k),
+                    ((0, 0), (0, Np - N)), constant_values=k)
+    adw_t = jnp.pad(adw.astype(jnp.float32).T, ((0, 0), (0, Np - N)))
+    conn_t = pl.pallas_call(
+        functools.partial(_lp_conn_kernel, k=k),
+        grid=(Np // TILE_V,),
         in_specs=[
-            pl.BlockSpec((TILE_V, DEG), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_V, DEG), lambda i: (i, 0)),
-            pl.BlockSpec((N,), lambda i: (0,)),           # full part vector
-            pl.BlockSpec((TILE_V,), lambda i: (i,)),      # this tile's blocks
+            pl.BlockSpec((DEG, TILE_V), lambda i: (0, i)),
+            pl.BlockSpec((DEG, TILE_V), lambda i: (0, i)),
         ],
-        out_specs=[
-            pl.BlockSpec((TILE_V, k), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_V,), lambda i: (i,)),
-            pl.BlockSpec((TILE_V,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Np, k), jnp.float32),
-            jax.ShapeDtypeStruct((Np,), jnp.int32),
-            jax.ShapeDtypeStruct((Np,), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((k, TILE_V), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((k, Np), jnp.float32),
         interpret=interpret,
-    )(adj_p, adw_p, part, part_p)
-    return conn[:N], best[:N], gain[:N]
+    )(nbr_t, adw_t)
+    conn = conn_t[:, :N].T
+    best, gain = ref.best_gain(conn, part)
+    return conn, best, gain

@@ -1,21 +1,22 @@
 """Pallas TPU kernel: J(C,D,Pi) communication-cost evaluation.
 
-Edge-parallel: the grid tiles the directed edge arrays; each program
-instance streams a ``(TILE_E,)`` slice of (rows, cols, ewgt) from HBM into
-VMEM, gathers both endpoint PE ids from the (VMEM-resident) assignment
-vector, computes the hierarchy distance with the mixed-radix bit-label
-trick entirely in registers, and writes a per-tile partial sum. The final
-reduction over tiles happens in the caller.
+Edge-parallel: XLA gathers both endpoint PE ids (``pe_of[rows]``,
+``pe_of[cols]``) and lays the edges out as ``[R, 128]`` tiles
+(``ref.edge_tiles``); the grid streams ``(EDGE_TILE_ROWS, 128)`` tiles of
+(pe_u, pe_v, w) from HBM into VMEM, computes the hierarchy distance with
+the mixed-radix group-id comparison in registers, and writes one aligned
+``(8, 128)`` block of partial sums per tile. ``ops.mapcost`` folds the
+partials into J with one program shared by both backends
+(``ref.fold_partials``).
 
 TPU adaptation notes:
 * The hot operation in the C++ code is a scalar hash-table / array gather
-  per edge; here the per-edge distance is a dense [TILE_E, l] integer-divide
-  + compare + popcount-style reduction on the VPU — no MXU involvement.
-* ``pe_of`` (and the tiny ``g_below``/``dvec`` tables) are small enough for
-  VMEM (4 B x N; N <= 2^20 fits comfortably), so each edge tile performs
-  two vector gathers against VMEM instead of HBM random access — the TPU
-  analogue of the paper's O(1) bit-label distance queries.
-* TILE_E is a multiple of 8*128 to match VREG lanes.
+  per edge; here the per-edge distance is a dense [TILE, l] integer-divide
+  + compare + count on the VPU — no MXU involvement.
+* The tiny ``g_below``/``dvec`` tables live in SMEM as scalars.
+* The tile body (``ref.edge_cost_partials``) is shared with the oracle and
+  folds its rows in a fixed add chain, so the partials are bitwise equal
+  across backends.
 """
 from __future__ import annotations
 
@@ -24,28 +25,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-TILE_E = 2048  # 2 * (8, 128) VREG tiles worth of edges
+from . import ref
 
 
-def _mapcost_kernel(rows_ref, cols_ref, ewgt_ref, pe_ref, gb_ref, dv_ref, out_ref):
-    rows = rows_ref[...]
-    cols = cols_ref[...]
-    w = ewgt_ref[...]
-    pe = pe_ref[...]
-    pu = pe[rows]
-    pv = pe[cols]
+def _mapcost_kernel(gb_ref, dv_ref, pu_ref, pv_ref, w_ref, out_ref):
     l = gb_ref.shape[0]
-    lvl = jnp.zeros(rows.shape, jnp.int32)
-    d = jnp.zeros(rows.shape, jnp.float32)
-    # l is tiny (2..4): unrolled compare/select chain per level
-    for i in range(l):
-        gb = gb_ref[i]
-        differs = (pu // gb) != (pv // gb)
-        lvl = lvl + differs.astype(jnp.int32)
-    for i in range(l):
-        d = jnp.where(lvl == i + 1, dv_ref[i], d)
-    out_ref[0] = jnp.sum(w * d)
+    out_ref[...] = ref.edge_cost_partials(
+        pu_ref[...], pv_ref[...], w_ref[...],
+        [gb_ref[i] for i in range(l)], [dv_ref[i] for i in range(l)])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -56,31 +45,22 @@ def mapcost_pallas(
     pe_of: jax.Array,
     g_below: jax.Array,
     dvec: jax.Array,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
-    """J(C,D,Pi) via the Pallas kernel. Pads the edge arrays to TILE_E."""
-    M = rows.shape[0]
-    Mp = ((M + TILE_E - 1) // TILE_E) * TILE_E
-    pad = Mp - M
-    N = pe_of.shape[0]
-    rows = jnp.pad(rows, (0, pad))
-    cols = jnp.pad(cols, (0, pad))
-    ewgt = jnp.pad(ewgt, (0, pad))
-    grid = (Mp // TILE_E,)
-
+    """Partial sums of J(C,D,Pi) via the Pallas kernel (edges padded to
+    whole tiles); bitwise equal to ``ref.mapcost_partials_ref``."""
+    pu, pv, w = ref.edge_tiles(rows, cols, ewgt, pe_of)
+    t = pu.shape[0] // ref.EDGE_TILE_ROWS
+    tile = pl.BlockSpec((ref.EDGE_TILE_ROWS, ref.LANES), lambda i: (i, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     partial = pl.pallas_call(
         _mapcost_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE_E,), lambda i: (i,)),
-            pl.BlockSpec((TILE_E,), lambda i: (i,)),
-            pl.BlockSpec((TILE_E,), lambda i: (i,)),
-            pl.BlockSpec((N,), lambda i: (0,)),           # pe_of: whole vector in VMEM
-            pl.BlockSpec((g_below.shape[0],), lambda i: (0,)),
-            pl.BlockSpec((dvec.shape[0],), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((grid[0],), jnp.float32),
+        grid=(t,),
+        in_specs=[smem, smem, tile, tile, tile],
+        out_specs=pl.BlockSpec((ref.SUBLANES, ref.LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((t * ref.SUBLANES, ref.LANES),
+                                       jnp.float32),
         interpret=interpret,
-    )(rows, cols, ewgt, pe_of, g_below, dvec)
-    return jnp.sum(partial) / 2.0
+    )(g_below.astype(jnp.int32), dvec.astype(jnp.float32), pu, pv, w)
+    return partial

@@ -1,25 +1,27 @@
 """Jitted public wrappers for the kernels — the single dispatch point.
 
-Every caller that wants a kernel (refinement gain pass, mapping-cost
-evaluation, attention) goes through this module; nothing else in the repo
-decides pallas-vs-XLA on its own. The policy lives in one helper:
+Every caller that wants a kernel (refinement gain pass, coarsening,
+mapping-cost evaluation, attention) goes through this module; nothing else
+in the repo decides pallas-vs-XLA on its own. The policy lives in one
+helper:
 
 ``kernel_backend()`` returns one of
 
-* ``"pallas"``    — a real TPU backend is present: Pallas kernels run
-                    COMPILED (``interpret=False``).
-* ``"interpret"`` — forced via ``REPRO_KERNEL_BACKEND=interpret``: Pallas
-                    kernels run under the interpreter (CI parity testing on
-                    CPU; slow).
-* ``"xla"``       — anything else (CPU/GPU default): the pure-jnp reference
+* ``"pallas"``    — the default on a TPU: Pallas kernels run COMPILED
+                    (``interpret=False``).
+* ``"interpret"`` — only when ``REPRO_KERNEL_BACKEND=interpret`` asks for
+                    it: Pallas kernels run under the interpreter (parity
+                    testing on the CPU; slow).
+* ``"xla"``       — the default off a TPU: the pure-jnp reference
                     implementations, which XLA fuses well.
 
 ``REPRO_KERNEL_BACKEND`` overrides the device-derived default with any of
-the three values; per-call ``use_pallas=`` arguments override both.
+the three values; any other value raises. A per-call ``use_pallas=`` picks
+kernel or reference but never the mode: the kernel runs compiled unless
+the environment asks for interpret mode.
 """
 from __future__ import annotations
 
-import functools
 import os
 
 import jax
@@ -30,44 +32,57 @@ from .coarsen_kernels import contract_edges_pallas, hem_propose_pallas
 from .flashattn import flash_attention_pallas
 from .lp_gain import lp_gain_pallas
 from .mapcost import mapcost_pallas
-from .split import gather_rows_pallas
+
+BACKENDS = ("pallas", "interpret", "xla")
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def kernel_backend(platform: str | None = None) -> str:
+    """Resolve the kernel dispatch policy (see module docstring).
 
-
-def kernel_backend() -> str:
-    """Resolve the kernel dispatch policy (see module docstring)."""
-    forced = os.environ.get("REPRO_KERNEL_BACKEND", "").lower()
-    if forced in ("pallas", "interpret", "xla"):
+    ``platform`` names the JAX platform the kernels will run on; None asks
+    this process's default backend (which starts it).
+    """
+    forced = os.environ.get("REPRO_KERNEL_BACKEND", "")
+    if forced:
+        if forced not in BACKENDS:
+            raise ValueError(f"REPRO_KERNEL_BACKEND={forced!r} is not one of "
+                             f"{BACKENDS}")
         return forced
-    return "pallas" if _on_tpu() else "xla"
+    if platform is None:
+        platform = jax.default_backend()
+    return "pallas" if platform == "tpu" else "xla"
 
 
 def dispatch(use_pallas: bool | None = None) -> tuple[bool, bool]:
     """(use_pallas, interpret) for a kernel call.
 
     ``use_pallas=None`` defers to :func:`kernel_backend`; an explicit bool
-    keeps the old per-call override semantics (interpret mode is then
-    enabled exactly when no real TPU is present).
+    chooses kernel or reference. Interpret mode is on only where
+    ``REPRO_KERNEL_BACKEND=interpret`` says so.
     """
+    backend = kernel_backend()
     if use_pallas is None:
-        backend = kernel_backend()
-        return backend != "xla", backend == "interpret"
-    return use_pallas, not _on_tpu()
+        use_pallas = backend != "xla"
+    return use_pallas, backend == "interpret"
 
 
-_mapcost_ref_jit = jax.jit(ref.mapcost_ref)
+_mapcost_partials_ref = jax.jit(ref.mapcost_partials_ref)
+_fold_partials = jax.jit(ref.fold_partials)
 
 
 def mapcost(rows, cols, ewgt, pe_of, g_below, dvec, use_pallas: bool | None = None):
-    """J(C, D, Pi) over directed edge arrays (padding weight must be 0)."""
+    """J(C, D, Pi) over directed edge arrays (padding weight must be 0).
+
+    Kernel and reference produce bitwise-equal partial sums, and one
+    separately compiled program folds either, so J agrees bitwise too.
+    """
     use_pallas, interpret = dispatch(use_pallas)
     if use_pallas:
-        return mapcost_pallas(rows, cols, ewgt, pe_of, g_below, dvec,
+        part = mapcost_pallas(rows, cols, ewgt, pe_of, g_below, dvec,
                               interpret=interpret)
-    return _mapcost_ref_jit(rows, cols, ewgt, pe_of, g_below, dvec)
+    else:
+        part = _mapcost_partials_ref(rows, cols, ewgt, pe_of, g_below, dvec)
+    return _fold_partials(part)
 
 
 def lp_gain(adj, adw, part, k: int, use_pallas: bool | None = None):
@@ -78,17 +93,14 @@ def lp_gain(adj, adw, part, k: int, use_pallas: bool | None = None):
     return ref.lp_gain_ref(adj, adw, part, k)
 
 
-def gather_rows(src, idx, use_pallas: bool | None = None):
+def gather_rows(src, idx):
     """Masked-compaction gather for the split op: out[b,j] = src[idx[b,j]].
 
-    ``idx`` is clipped in-range inside both implementations; pure data
-    movement, so pallas/interpret/xla agree BITWISE (the device-resident
-    multisection's determinism depends on this; tested in test_kernels).
+    ``idx`` is clipped in range. This stays in XLA on every backend: it is
+    a 1-D gather, which Mosaic does not lower, and pure data movement, so
+    there is nothing for a kernel to add.
     """
-    use_pallas, interpret = dispatch(use_pallas)
-    if use_pallas:
-        return gather_rows_pallas(src, idx, interpret=interpret)
-    return ref.gather_rows_ref(src, idx)
+    return jnp.take(src, jnp.clip(idx, 0, src.shape[0] - 1))
 
 
 def hem_propose(adj, adw, jit, matched, use_pallas: bool | None = None):
@@ -112,7 +124,7 @@ def contract_edges(cand, candw, use_pallas: bool | None = None):
     ``cand [N, D2]`` holds the coarse-mapped neighbour candidates of each
     coarse row's fine members (sentinel N = invalid, weight 0). Returns
     ``(nbr, w, cnt)``; weight totals use a fixed add chain, so backends
-    agree BITWISE (see kernels/ref.py:merge_dedup_rows).
+    agree BITWISE (see kernels/ref.py:merge_dedup_t).
     """
     use_pallas, interpret = dispatch(use_pallas)
     if use_pallas:

@@ -146,8 +146,6 @@ def run_cell(arch: str, cell, multi_pod: bool, knobs: dict | None = None,
                                 + ma.output_size_in_bytes - ma.alias_size_in_bytes),
     }
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax<0.5 returns one dict per program
-        ca = ca[0] if ca else {}
     rec["cost_analysis"] = {"flops": float(ca.get("flops", -1)),
                             "bytes_accessed": float(ca.get("bytes accessed", -1))}
 

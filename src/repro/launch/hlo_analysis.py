@@ -33,8 +33,10 @@ _DTYPE_BYTES = {
 }
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-_OP_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\([^)]*\)|[\w\[\],{}\s]+?)\s+([\w\-]+)\(")
+_OP_HEAD_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*")
+# one array type; a TPU layout carries tiling in braces: {1,0:T(8,128)S(1)}
+_ARRAY_TYPE_RE = re.compile(r"\w+\[[\d,]*\](?:\{[^}]*\})?")
+_KIND_RE = re.compile(r"\s*([\w\-]+)\(")
 _CALLED_RE = re.compile(r"(?:calls=|to_apply=|condition=|body=)%?([\w.\-]+)")
 _COMP_HDR_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s*->\s*.+\s*\{")
 
@@ -79,6 +81,39 @@ class Computation:
     is_entry: bool
 
 
+def _balanced_end(text: str, start: int) -> int:
+    """Index just past the bracket group that opens at ``text[start]``."""
+    depth = 0
+    for i in range(start, len(text)):
+        if text[i] in "([{":
+            depth += 1
+        elif text[i] in ")]}":
+            depth -= 1
+            if depth == 0:
+                return i + 1
+    return len(text)
+
+
+def _parse_op(line: str) -> Op | None:
+    """``name = type kind(operands), attrs`` -> Op; None for other lines.
+    The type is an array type or a (nested) tuple of them."""
+    head = _OP_HEAD_RE.match(line)
+    if not head:
+        return None
+    pos = head.end()
+    if line.startswith("(", pos):
+        end = _balanced_end(line, pos)
+    else:
+        t = _ARRAY_TYPE_RE.match(line, pos)
+        if not t:
+            return None
+        end = t.end()
+    kind = _KIND_RE.match(line, end)
+    if not kind:
+        return None
+    return Op(head.group(1), line[pos:end], kind.group(1), line)
+
+
 def parse_computations(hlo: str) -> dict[str, Computation]:
     comps: dict[str, Computation] = {}
     cur: Computation | None = None
@@ -93,9 +128,9 @@ def parse_computations(hlo: str) -> dict[str, Computation]:
         if line.strip() == "}":
             cur = None
             continue
-        m = _OP_RE.match(line)
-        if m:
-            cur.ops.append(Op(m.group(1), m.group(2), m.group(3), line))
+        op = _parse_op(line)
+        if op is not None:
+            cur.ops.append(op)
     return comps
 
 
@@ -106,13 +141,14 @@ def _operands(op: Op) -> list[str]:
     with their type inlined (``f32[128,256]{1,0} %name``) — the name is
     always the last whitespace-separated token.
     """
-    m = re.search(re.escape(op.kind) + r"\(([^)]*)\)", op.line)
+    m = re.search(r"\s" + re.escape(op.kind) + r"\(", op.line)
     if not m:
         return []
+    inner = op.line[m.end():_balanced_end(op.line, m.end() - 1) - 1]
     # split on commas at bracket depth 0 only — inlined operand types carry
     # commas of their own (f32[128,256]{1,0})
     pieces, cur, depth = [], [], 0
-    for ch in m.group(1):
+    for ch in inner:
         if ch in "[{(":
             depth += 1
         elif ch in "]})":

@@ -23,24 +23,17 @@ from repro.core.hierarchy import Hierarchy
 from repro.core.taskgraph import TaskGraph
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types`` appeared in jax 0.5; omit it on older releases (the
-    pre-0.5 default is the same Auto behaviour)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False, device_order: str = "default"):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     if device_order == "default":
-        return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+        return jax.make_mesh(shape, axes,
+                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
     if device_order == "sharedmap":
         perm = sharedmap_device_order(multi_pod=multi_pod)
         devices = np.asarray(jax.devices())[perm].reshape(shape)
-        return jax.sharding.Mesh(devices, axes, **_axis_types_kwargs(len(axes)))
+        return jax.sharding.Mesh(
+            devices, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
     raise ValueError(device_order)
 
 

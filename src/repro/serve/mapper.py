@@ -120,7 +120,7 @@ from repro.serve.admission import (ADMIT, ADMIT_DEGRADED, PREEMPT, SHED,
                                    RetryPolicy, ServiceClosedError,
                                    ServiceOverloadError)
 from repro.serve.store import ResultStore
-from repro.serve.supervisor import SupervisedWorkerPool
+from repro.serve.supervisor import SupervisedWorkerPool, worker_platform
 from repro.serve.tracker import NULL_TRACKER, Tracker, safe_emit
 
 _PLANNABLE = ("bucket", "layer", "device")
@@ -162,14 +162,16 @@ def graph_fingerprint(g: Graph, h: Hierarchy,
 
 
 def request_fingerprint(g: Graph, h: Hierarchy, cfg: SharedMapConfig,
-                        tg: TaskGraph | None = None) -> bytes:
+                        tg: TaskGraph | None = None,
+                        platform: str | None = None) -> bytes:
     """Content address of a mapping request: the graph fingerprint plus
     every config field that influences the result. ``backend`` enters
-    resolved, so auto/xla hit the same entry off-TPU."""
+    resolved for ``platform`` (None: this process's), so auto/xla hit the
+    same entry off-TPU."""
     hs = hashlib.blake2b(digest_size=16)
     hs.update(graph_fingerprint(g, h, tg))
     hs.update(repr((float(cfg.eps), cfg.preset, cfg.strategy, int(cfg.seed),
-                    bool(cfg.adaptive), resolve_backend(cfg.backend),
+                    bool(cfg.adaptive), resolve_backend(cfg.backend, platform),
                     bool(cfg.refine_mapping))).encode())
     return hs.digest()
 
@@ -294,7 +296,11 @@ class MappingService:
         (incl. SIGKILL) are detected, workers restart with capped backoff,
         in-flight requests are re-dispatched. Trades cross-request
         coalescing for crash isolation. 0 (default) keeps PR 5's
-        in-process execution.
+        in-process execution. On an accelerator host each worker holds
+        one chip and this process none: it starts no JAX backend, keeps
+        graphs as host arrays, and so cannot serve anything itself —
+        ``degrade_on_failure``, ``degrade_on_overload`` and shadow
+        verification, which would map here, are refused.
     worker_kwargs: extra keyword arguments for
         :class:`SupervisedWorkerPool` (heartbeat_s, hang_timeout_s, ...).
     shadow_verify_fraction: fraction (0..1) of ``strategy="device"``
@@ -333,10 +339,23 @@ class MappingService:
         if self.store is None and store_path is not None:
             self.store = ResultStore(store_path, fault_injector=fault_injector)
         self.supervisor: SupervisedWorkerPool | None = None
+        self._platform: str | None = None  # None: this process maps
         if int(workers) > 0:
+            platform = worker_platform()
+            self._platform = platform[0]
+            local = [name for name, on in (
+                ("degrade_on_failure", degrade_on_failure),
+                ("degrade_on_overload", degrade_on_overload),
+                ("shadow_verify_fraction", shadow_verify_fraction > 0))
+                if on]
+            if self._platform != "cpu" and local:
+                raise ValueError(
+                    f"workers={int(workers)} on a {self._platform} host maps "
+                    f"only in the workers, but {', '.join(local)} would map "
+                    "in this process, which holds no chip; turn them off")
             self.supervisor = SupervisedWorkerPool(
                 int(workers), fault_injector=fault_injector, tracker=tracker,
-                **(worker_kwargs or {}))
+                platform=platform, **(worker_kwargs or {}))
         self.shadow_verify_fraction = float(shadow_verify_fraction)
         self._shadow_seq = 0
         self._device_quarantined = False
@@ -394,14 +413,15 @@ class MappingService:
         cfg = config or SharedMapConfig()
         tg = g if isinstance(g, TaskGraph) else None
         if tg is not None:
-            g = tg.to_graph()
+            # worker mode forwards host arrays: this process maps nothing
+            g = tg.to_graph(host=self.supervisor is not None)
         if self.validate:
             validate_request(g, h, cfg)
         fut: Future = Future()
         deadline = None
         if deadline_s is not None:
             deadline = time.monotonic() + float(deadline_s)
-        fp = request_fingerprint(g, h, cfg, tg)
+        fp = request_fingerprint(g, h, cfg, tg, self._platform)
         cached = self._cache_get(fp)
         if cached is not None:
             fut.set_result(self._result_copy(cached, cache_hit=True))
@@ -474,7 +494,7 @@ class MappingService:
             # request is served with the cheapest preset, cached under the
             # DEGRADED config's fingerprint (never the original's).
             cfg = dataclasses.replace(cfg, preset="fast")
-            fp = request_fingerprint(g, h, cfg, tg)
+            fp = request_fingerprint(g, h, cfg, tg, self._platform)
             degradation = {"level": DEGRADE_FAST_PRESET,
                            "mode": "fast_preset", "reason": "overload"}
             adm.note_degraded()
@@ -559,7 +579,12 @@ class MappingService:
         covers all traffic). ``ell_degs`` optionally pins the static ELL
         degree caps to warm for the kernel backend (default: derived from
         the dummy graph, which is what xla — the common case — ignores).
+        Compiles in this process, so a service whose workers hold the
+        chips refuses it.
         """
+        if self._platform not in (None, "cpu"):
+            raise ValueError("warmup compiles in this process, but its "
+                             "workers hold the chips")
         backend = resolve_backend(backend)
         t0 = time.time()
         programs = 0

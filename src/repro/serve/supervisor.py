@@ -27,6 +27,13 @@ supervision layer:
   a row fails, with a typed :class:`WorkerCrashError` that advertises
   itself ``transient`` (the service's retry/degradation ladder takes it
   from there). Zero unresolved futures is the contract, crash or not.
+* **One chip per worker** — JAX gives a device to one process at a time,
+  so the pool settles the workers' platform before it spawns them
+  (:func:`worker_platform`, which starts no JAX backend in this process)
+  and pins it in each worker's environment: on a TPU host worker ``i``
+  sees chip ``i`` alone, and more workers than chips is an error; on a
+  CPU host every worker is pinned to the CPU. A worker never falls back
+  to another platform on its own.
 * **Deterministic fault injection** — the ``worker_kill`` seam of a
   ``repro.faults.FaultInjector`` is checked right after each dispatch; a
   fired fault SIGKILLs the worker the task was just sent to. Tests drive
@@ -44,10 +51,14 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
+import json
 import os
 import pickle
 import queue as queue_mod
 import signal
+import socket
+import subprocess
+import sys
 import threading
 import time
 import traceback
@@ -84,6 +95,94 @@ class WorkerTaskError(RuntimeError):
     carries its repr + traceback text instead."""
 
 
+_PROBE = ("import json, jax; d = jax.devices(); "
+          "print(json.dumps([d[0].platform, len(d)]))")
+
+
+def _backend_started() -> bool:
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
+
+
+def worker_platform() -> tuple[str, int]:
+    """``(platform, device count)`` that a spawned worker would get,
+    found without starting a JAX backend in this process.
+
+    ``JAX_PLATFORMS=cpu`` answers at once. A process whose backend has
+    already started answers from it, and raises if it holds an
+    accelerator: its workers could not have the chips. Otherwise a short
+    child process asks JAX and exits, releasing what it opened.
+    """
+    if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
+        return "cpu", 1
+    if _backend_started():
+        import jax
+        platform = jax.default_backend()
+        if platform != "cpu":
+            raise RuntimeError(
+                f"this process has already started the {platform!r} backend "
+                "and holds its devices, so worker processes cannot have "
+                "them: create the worker pool before any JAX computation, "
+                "or use workers=0")
+        return "cpu", 1
+    out = subprocess.run([sys.executable, "-c", _PROBE], capture_output=True,
+                         text=True, timeout=600, check=False)
+    if out.returncode != 0:
+        raise RuntimeError("could not ask JAX for the worker platform: "
+                           + out.stderr[-2000:])
+    platform, count = json.loads(out.stdout.strip().splitlines()[-1])
+    return str(platform), int(count)
+
+
+def _free_port() -> int:
+    with socket.socket() as sk:
+        sk.bind(("localhost", 0))
+        return sk.getsockname()[1]
+
+
+def pinned_env(platform: str, chip: int) -> dict[str, str]:
+    """Environment that gives a worker ``platform``, and on a TPU host
+    chip ``chip`` alone (libtpu's per-process chip-visibility variables,
+    as JAX's own multi-process launcher sets them)."""
+    if platform == "cpu":
+        return {"JAX_PLATFORMS": "cpu"}
+    if platform != "tpu":
+        raise ValueError(f"worker processes support cpu and tpu hosts, "
+                         f"not {platform!r}")
+    port = _free_port()
+    return {"JAX_PLATFORMS": "tpu",
+            "TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+            "TPU_PROCESS_PORT": str(port),
+            "CLOUD_TPU_TASK_ID": "0"}
+
+
+def _pin(env: dict[str, str]) -> None:
+    """Apply a worker's pinned environment before JAX starts a backend
+    (the spawn bootstrap may already have imported jax, whose platform
+    setting is read at import)."""
+    os.environ.update(env)
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", env["JAX_PLATFORMS"])
+    if env["JAX_PLATFORMS"] != "cpu":
+        import jax
+        jax.devices()  # claim the chip now, before any task arrives
+
+
+def device_info() -> dict:
+    """Platform, kind and identity of this process's first device."""
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind, "id": int(d.id),
+            "coords": list(getattr(d, "coords", None) or ()),
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "pid": os.getpid()}
+
+
 def _resolve_fn(path: str):
     mod, _, attr = path.partition(":")
     if not attr:
@@ -91,14 +190,17 @@ def _resolve_fn(path: str):
     return getattr(importlib.import_module(mod), attr)
 
 
-def _worker_main(wid: int, inbox, outbox, hb_interval_s: float) -> None:
-    """Worker process body: heartbeat thread + task loop.
+def _worker_main(wid: int, inbox, outbox, hb_interval_s: float,
+                 env: dict[str, str]) -> None:
+    """Worker process body: pin the platform, then heartbeat thread +
+    task loop.
 
     Messages in: ``(task_id, fn_path, payload)`` or ``None`` (shutdown).
     Messages out: ``("hb", wid)``, ``("ok", task_id, wid, result)``,
     ``("err", task_id, wid, pickled_exc_or_text)``.
     """
     signal.signal(signal.SIGINT, signal.SIG_IGN)  # parent owns shutdown
+    _pin(env)
     stop = threading.Event()
 
     def beat():
@@ -158,7 +260,7 @@ class SupervisedWorkerPool:
 
     Parameters
     ----------
-    workers: process count.
+    workers: process count; on a TPU host at most one per chip.
     ctx: multiprocessing start method ("spawn" default — fork duplicates
         the parent's JAX/XLA runtime state, which is undefined behavior).
     heartbeat_s: worker heartbeat period (health signal).
@@ -172,6 +274,8 @@ class SupervisedWorkerPool:
         killing its worker before its Future fails with WorkerCrashError.
     fault_injector: ``worker_kill`` seam — a fired occurrence SIGKILLs the
         worker the task was just dispatched to (deterministic crash tests).
+    platform: ``(platform, device count)`` of the workers when the caller
+        has already asked :func:`worker_platform`; None asks it here.
     """
 
     def __init__(self, workers: int = 2, *, ctx: str = "spawn",
@@ -180,7 +284,8 @@ class SupervisedWorkerPool:
                  restart_backoff_cap_s: float = 2.0,
                  max_redispatch: int = 2, poll_s: float = 0.02,
                  fault_injector: FaultInjector = NULL_INJECTOR,
-                 tracker: Tracker = NULL_TRACKER):
+                 tracker: Tracker = NULL_TRACKER,
+                 platform: tuple[str, int] | None = None):
         import multiprocessing as mp
         self._mp = mp.get_context(ctx)
         self.heartbeat_s = float(heartbeat_s)
@@ -191,6 +296,11 @@ class SupervisedWorkerPool:
         self.poll_s = float(poll_s)
         self.faults = fault_injector
         self.tracker = tracker
+        self.platform, chips = platform or worker_platform()
+        if self.platform != "cpu" and int(workers) > chips:
+            raise ValueError(f"{int(workers)} workers but this host has "
+                             f"{chips} {self.platform} chip(s): one worker "
+                             "per chip at most")
         self._lock = threading.Lock()
         self._closed = False
         self._seq = 0
@@ -300,7 +410,8 @@ class SupervisedWorkerPool:
         w.outbox = self._mp.Queue()
         w.proc = self._mp.Process(
             target=_worker_main,
-            args=(w.wid, w.inbox, w.outbox, self.heartbeat_s),
+            args=(w.wid, w.inbox, w.outbox, self.heartbeat_s,
+                  pinned_env(self.platform, w.wid)),
             daemon=True, name=f"mapper-worker-{w.wid}")
         w.proc.start()
         w.last_hb = time.monotonic()
@@ -524,12 +635,15 @@ def mapping_task(payload: dict) -> dict:
     res = shared_map_direct(g, h, cfg, checkpoint=checkpoint,
                             resident=payload.get("resident"))
     return {"pe_of": np.asarray(res.pe_of), "J": float(res.J),
-            "stats": res.stats}
+            "stats": {**res.stats, "device": device_info()}}
 
 
 def echo_task(payload: dict) -> dict:
     """Trivial task for pool tests/benchmarks: optional sleep, optional
-    self-SIGKILL (a worker crash with no injector involved), then echo."""
+    self-SIGKILL (a worker crash with no injector involved), then echo
+    (with the worker's values of the variables named in ``getenv``)."""
+    if payload.get("getenv"):
+        return {k: os.environ.get(k) for k in payload["getenv"]}
     if payload.get("sleep_s"):
         time.sleep(float(payload["sleep_s"]))
     if payload.get("die"):

@@ -97,8 +97,9 @@ def test_contract_edges_parity_random(seed):
         assert np.array_equal(np.asarray(xa), np.asarray(xb))
 
 
-def test_ops_coarsen_dispatch():
+def test_ops_coarsen_dispatch(monkeypatch):
     """ops wrappers return identical values through either backend flag."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
     rng = np.random.default_rng(9)
     n, deg = 200, 8
     adj, adw = _rand_ell(rng, n, deg)

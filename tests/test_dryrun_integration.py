@@ -43,9 +43,7 @@ def test_dryrun_device_order_sharedmap(tmp_path):
         "x = jax.ShapeDtypeStruct((512, 64), jnp.float32,\n"
         "    sharding=NamedSharding(mesh, P(('pod','data'), 'model')))\n"
         "c = jax.jit(lambda a: (a * 2).sum()).lower(x).compile()\n"
-        "ca = c.cost_analysis()\n"
-        "ca = ca[0] if isinstance(ca, (list, tuple)) else ca\n"  # jax<0.5
-        "print('OK', ca['flops'])\n"
+        "print('OK', c.cost_analysis()['flops'])\n"
     )
     env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
     proc = subprocess.run([sys.executable, "-c", script], env=env,

@@ -42,8 +42,9 @@ def test_flash_dtypes(dt):
                                np.asarray(b, np.float32), atol=atol)
 
 
-def test_flash_gqa_wrapper_matches_sdpa():
+def test_flash_gqa_wrapper_matches_sdpa(monkeypatch):
     """ops.flash_attention (GQA layout) vs the model's _sdpa path."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
     rng = np.random.default_rng(3)
     B, S, H, Hkv, D = 2, 128, 8, 2, 64
     q = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)

@@ -257,3 +257,34 @@ def test_dot_flops_exact():
             "  %d = f32[128,512] dot(%lhs, %rhs), "
             "lhs_contracting_dims={1}, rhs_contracting_dims={0}")
     assert _dot_flops(op, shapes) == 2 * 128 * 512 * 256
+
+
+# TPU-compiled HLO: layouts carry tiling in braces ({1,0:T(4,128)S(1)}),
+# and async copies return nested tuple types.
+TPU_HLO = """\
+HloModule fixture_tpu, is_scheduled=true
+
+%fused_computation.1 (p0: f32[4,384]) -> f32[4,384] {
+  %p0 = f32[4,384]{1,0:T(4,128)} parameter(0)
+  ROOT %neg = f32[4,384]{1,0:T(4,128)} negate(%p0)
+}
+
+ENTRY %main.3 (a: f32[4,384]) -> f32[4,384] {
+  %a = f32[4,384]{1,0:T(4,128)} parameter(0)
+  %copy-start = (f32[4,384]{1,0:T(4,128)S(1)}, f32[4,384]{1,0:T(4,128)}, u32[]{:S(2)}) copy-start(%a)
+  %copy-done = f32[4,384]{1,0:T(4,128)S(1)} copy-done(%copy-start)
+  ROOT %f = f32[4,384]{1,0:T(4,128)} fusion(%copy-done), kind=kLoop, calls=%fused_computation.1
+}
+"""
+
+
+def test_tpu_layouts_and_async_tuples_parse():
+    comps = parse_computations(TPU_HLO)
+    entry = comps["main.3"]
+    assert [op.kind for op in entry.ops] == [
+        "parameter", "copy-start", "copy-done", "fusion"]
+    start = entry.ops[1]
+    assert _shape_bytes(start.type_str) == 2 * 4 * 384 * 4 + 4
+    assert _operands(entry.ops[3]) == ["copy-done"]
+    tg = extract_comm_graph(TPU_HLO)
+    assert tg.n >= 2 and tg.m >= 1

@@ -29,9 +29,11 @@ def test_mapcost_shapes(n, m, hier):
     rows, cols, w, pe = _edge_arrays(n, m, h.k, seed=n + m)
     gb = jnp.asarray((1,) + h.strides[:-1], jnp.int32)
     dv = jnp.asarray(h.d, jnp.float32)
-    a = ref.mapcost_ref(rows, cols, w, pe, gb, dv)
+    a = ref.mapcost_partials_ref(rows, cols, w, pe, gb, dv)
     b = mapcost_pallas(rows, cols, w, pe, gb, dv, interpret=True)
-    np.testing.assert_allclose(float(a), float(b), rtol=1e-5)
+    assert np.array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_allclose(float(ref.mapcost_ref(rows, cols, w, pe, gb, dv)),
+                               float(ref.fold_partials(b)), rtol=1e-5)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -42,7 +44,7 @@ def test_mapcost_dtypes(dtype):
     dv = jnp.asarray(h.d, jnp.float32)
     a = ref.mapcost_ref(rows, cols, w.astype(jnp.float32), pe, gb, dv)
     b = mapcost_pallas(rows, cols, w.astype(jnp.float32), pe, gb, dv, interpret=True)
-    np.testing.assert_allclose(float(a), float(b), rtol=2e-3)
+    np.testing.assert_allclose(float(a), float(ref.fold_partials(b)), rtol=2e-3)
 
 
 @pytest.mark.parametrize("n,deg,k", [(128, 8, 4), (300, 16, 8), (1024, 32, 16), (77, 128, 3)])
@@ -74,15 +76,16 @@ def test_csr_to_ell_roundtrip(seed):
     np.testing.assert_allclose(np.asarray(conn_ell), np.asarray(conn_csr), atol=1e-4)
 
 
-def test_ops_dispatch():
+def test_ops_dispatch(monkeypatch):
     """ops.py returns identical numbers through either backend flag."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
     h = Hierarchy(a=(4, 2), d=(1.0, 10.0))
     rows, cols, w, pe = _edge_arrays(200, 600, h.k, seed=3)
     gb = jnp.asarray((1,) + h.strides[:-1], jnp.int32)
     dv = jnp.asarray(h.d, jnp.float32)
     a = ops.mapcost(rows, cols, w, pe, gb, dv, use_pallas=False)
     b = ops.mapcost(rows, cols, w, pe, gb, dv, use_pallas=True)
-    np.testing.assert_allclose(float(a), float(b), rtol=1e-5)
+    assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
 # --- PR3: randomized kernel parity (property-style, seeded loops) -------------
@@ -112,9 +115,9 @@ def test_mapcost_parity_random(seed):
     dv = jnp.asarray(h.d, jnp.float32)
     args = (jnp.asarray(rows, jnp.int32), jnp.asarray(cols, jnp.int32),
             jnp.asarray(w), pe, gb, dv)
-    a = ref.mapcost_ref(*args)
+    a = ref.mapcost_partials_ref(*args)
     b = mapcost_pallas(*args, interpret=True)
-    np.testing.assert_allclose(float(a), float(b), rtol=1e-5, atol=1e-5)
+    assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("seed", range(8))
@@ -159,8 +162,9 @@ def test_ell_adjacency_parity(seed):
                                    np.asarray(conn_c)[keep], atol=1e-4)
 
 
-def test_ops_lp_gain_dispatch():
+def test_ops_lp_gain_dispatch(monkeypatch):
     """ops.lp_gain returns identical numbers through either backend flag."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
     rng = np.random.default_rng(7)
     n, deg, k = 200, 12, 5
     adj = jnp.asarray(rng.integers(0, n + 1, (n, deg)), jnp.int32)
@@ -172,43 +176,9 @@ def test_ops_lp_gain_dispatch():
     assert np.array_equal(np.asarray(b1), np.asarray(b2))
 
 
-# --- PR7: gather_rows (device-resident split's data-movement kernel) ----------
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
-@pytest.mark.parametrize("shape", [(1, 64), (3, 257), (4, 1024), (2, 4096)])
-def test_gather_rows_parity(dtype, shape):
-    """gather_rows_pallas (interpret) == jnp oracle, bitwise — it is pure
-    data movement, so parity must be exact for float AND integer payloads
-    (split_blocks gathers weights, ids and relabeled endpoints through it)."""
-    from repro.kernels.split import gather_rows_pallas
-
-    K, L = shape
-    rng = np.random.default_rng(K * L)
-    S = 500
-    if dtype == jnp.float32:
-        src = jnp.asarray(rng.random(S), jnp.float32)
-    else:
-        src = jnp.asarray(rng.integers(-100, 100, S), jnp.int32)
-    idx = jnp.asarray(rng.integers(0, S + 40, (K, L)), jnp.int32)  # some OOB
-    a = ref.gather_rows_ref(src, idx)
-    b = gather_rows_pallas(src, idx, interpret=True)
-    assert a.dtype == b.dtype == dtype
-    assert np.array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_ops_gather_rows_dispatch():
-    """ops.gather_rows returns identical values through either backend."""
-    rng = np.random.default_rng(9)
-    src = jnp.asarray(rng.random(300), jnp.float32)
-    idx = jnp.asarray(rng.integers(0, 300, (2, 128)), jnp.int32)
-    a = ops.gather_rows(src, idx, use_pallas=False)
-    b = ops.gather_rows(src, idx, use_pallas=True)
-    assert np.array_equal(np.asarray(a), np.asarray(b))
-
-
 def test_split_blocks_backend_invariant(monkeypatch):
     """The on-device split must produce identical children whatever kernel
-    backend serves its gathers — it is pure data movement end to end."""
+    backend is selected — it is pure data movement end to end."""
     from repro.core import multisection as M
     from repro.core.graph import split_blocks
 
@@ -239,3 +209,54 @@ def test_kernel_backend_env(monkeypatch):
     assert ops.dispatch() == (False, False)
     monkeypatch.delenv("REPRO_KERNEL_BACKEND")
     assert ops.kernel_backend() in ("pallas", "xla")
+
+
+@pytest.mark.parametrize("value", ["tpu", "PALLAS", "compiled"])
+def test_kernel_backend_rejects_unknown(monkeypatch, value):
+    """A misspelt backend raises instead of falling back to the default."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", value)
+    with pytest.raises(ValueError, match="REPRO_KERNEL_BACKEND"):
+        ops.kernel_backend()
+
+
+def test_kernel_backend_follows_platform(monkeypatch):
+    monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
+    assert ops.kernel_backend("tpu") == "pallas"
+    assert ops.kernel_backend("cpu") == "xla"
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "xla")
+    assert ops.kernel_backend("tpu") == "xla"
+
+
+def test_explicit_use_pallas_never_interprets(monkeypatch):
+    """use_pallas=True picks the kernel; only the environment picks
+    interpret mode. Off a TPU a compiled kernel is refused, loudly."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "xla")
+    assert ops.dispatch(True) == (True, False)
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
+    assert ops.dispatch(True) == (True, True)
+    assert ops.dispatch(False) == (False, True)
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "xla")
+    h = Hierarchy(a=(4, 2), d=(1.0, 10.0))
+    rows, cols, w, pe = _edge_arrays(64, 128, h.k, seed=5)
+    gb = jnp.asarray((1,) + h.strides[:-1], jnp.int32)
+    dv = jnp.asarray(h.d, jnp.float32)
+    if jax.default_backend() != "tpu":
+        with pytest.raises(Exception):
+            ops.mapcost(rows, cols, w, pe, gb, dv, use_pallas=True)
+
+
+@pytest.mark.parametrize("wrapper", [
+    "lp_gain.lp_gain_pallas", "mapcost.mapcost_pallas",
+    "coarsen_kernels.hem_propose_pallas",
+    "coarsen_kernels.contract_edges_pallas",
+    "flashattn.flash_attention_pallas"])
+def test_pallas_wrappers_require_interpret(wrapper):
+    """No wrapper defaults to interpret mode: the flag is a required
+    keyword, so every call site states which mode it runs."""
+    import importlib
+    import inspect
+    mod, name = wrapper.split(".")
+    fn = getattr(importlib.import_module(f"repro.kernels.{mod}"), name)
+    p = inspect.signature(fn).parameters["interpret"]
+    assert p.kind is inspect.Parameter.KEYWORD_ONLY
+    assert p.default is inspect.Parameter.empty

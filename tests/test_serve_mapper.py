@@ -201,3 +201,62 @@ def test_submit_after_close_raises():
     svc.close()
     with pytest.raises(RuntimeError):
         svc.submit(G.gen_rgg(50, seed=1), H, CFG)
+
+
+# --- worker processes on an accelerator host ----------------------------------
+
+@pytest.mark.parametrize("options", [
+    {}, {"degrade_on_failure": False, "degrade_on_overload": True},
+    {"degrade_on_failure": False, "shadow_verify_fraction": 0.5}])
+def test_pinned_workers_refuse_local_mapping(monkeypatch, options):
+    """Where workers hold the chips, every option that would map in the
+    parent is refused before any worker is spawned."""
+    import repro.serve.mapper as SM
+    monkeypatch.setattr(SM, "worker_platform", lambda: ("tpu", 4))
+    with pytest.raises(ValueError, match="holds no chip"):
+        MappingService(workers=2, **options)
+
+
+def test_pinned_workers_refuse_warmup():
+    """warmup compiles in this process: refused where workers hold chips."""
+    svc = MappingService()
+    svc._platform = "tpu"  # what workers=N sets on a TPU host
+    try:
+        with pytest.raises(ValueError, match="workers hold the chips"):
+            svc.warmup([(64, 256)], [2])
+    finally:
+        svc.close()
+
+
+@pytest.mark.parametrize("kind", ["graph", "taskgraph"])
+def test_worker_mode_parent_starts_no_backend(kind):
+    """The parent of a worker pool forwards host arrays and never starts a
+    JAX backend; the worker reports the platform it mapped on."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = (
+        "from jax._src import xla_bridge\n"
+        "from repro.core import graph as G\n"
+        "from repro.core.api import SharedMapConfig\n"
+        "from repro.core.hierarchy import Hierarchy\n"
+        "from repro.core.taskgraph import TaskGraph\n"
+        "from repro.serve.mapper import MappingService\n"
+        "g = G.gen_grid(12, host=True)\n"
+        f"if {kind!r} == 'taskgraph':\n"
+        "    g = TaskGraph.from_edges(int(g.n), g.rows[g.rows < g.cols],\n"
+        "                             g.cols[g.rows < g.cols])\n"
+        "h = Hierarchy(a=(2, 2), d=(1.0, 10.0))\n"
+        "with MappingService(workers=1, degrade_on_failure=False) as svc:\n"
+        "    res = svc.map(g, h, SharedMapConfig(preset='fast'))\n"
+        "assert res.stats['device']['platform'] == 'cpu', res.stats['device']\n"
+        "assert sorted(set(res.pe_of.tolist())) == [0, 1, 2, 3]\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+        "print('OK')\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(root, "src"))
+    proc = subprocess.run([sys.executable, "-c", script], env=env, cwd=root,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "OK" in proc.stdout

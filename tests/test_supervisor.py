@@ -8,8 +8,10 @@ import time
 import pytest
 
 from repro.faults import FaultInjector
+from repro.serve import supervisor
 from repro.serve.supervisor import (SupervisedWorkerPool, WorkerCrashError,
-                                    WorkerPoolClosedError)
+                                    WorkerPoolClosedError, pinned_env,
+                                    worker_platform)
 
 ECHO = "repro.serve.supervisor:echo_task"
 FAST = {"restart_backoff_s": 0.01, "poll_s": 0.01}
@@ -117,3 +119,50 @@ def test_burst_with_random_kills_all_futures_resolve():
                 done += 1  # typed resolution still counts as resolved
         assert done == 12
         assert all(f.done() for f in futs)
+
+
+# --- one chip per worker -----------------------------------------------------
+
+def test_worker_platform_cpu_needs_no_probe(monkeypatch):
+    """JAX_PLATFORMS=cpu answers without starting a child process."""
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+
+    def no_probe(*a, **k):
+        raise AssertionError("probed a child process")
+
+    monkeypatch.setattr(supervisor.subprocess, "run", no_probe)
+    assert worker_platform() == ("cpu", 1)
+
+
+@pytest.mark.parametrize("chip", [0, 3])
+def test_pinned_env_gives_a_tpu_worker_one_chip(chip):
+    env = pinned_env("tpu", chip)
+    assert env["JAX_PLATFORMS"] == "tpu"
+    assert env["TPU_VISIBLE_CHIPS"] == str(chip)
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_ADDRESSES"] == f"localhost:{env['TPU_PROCESS_PORT']}"
+
+
+def test_pinned_env_cpu_and_unsupported_platforms():
+    assert pinned_env("cpu", 5) == {"JAX_PLATFORMS": "cpu"}
+    with pytest.raises(ValueError, match="gpu"):
+        pinned_env("gpu", 0)
+
+
+def test_more_workers_than_chips_is_an_error():
+    with pytest.raises(ValueError, match="one worker per chip"):
+        SupervisedWorkerPool(3, platform=("tpu", 2), **FAST)
+
+
+def test_workers_inherit_a_pinned_platform():
+    """A worker is pinned to the platform the pool settled on, and a
+    respawned worker is pinned again."""
+    inj = FaultInjector(fail_at={"worker_kill": (0,)})
+    keys = ["JAX_PLATFORMS", "TPU_VISIBLE_CHIPS"]
+    with SupervisedWorkerPool(1, platform=("cpu", 1), fault_injector=inj,
+                              **FAST) as pool:
+        for _ in range(2):  # the first dispatch kills its worker
+            env = pool.submit(ECHO, {"getenv": keys}).result(timeout=60)
+            assert env == {"JAX_PLATFORMS": "cpu", "TPU_VISIBLE_CHIPS": None}
+        assert pool.stats()["restarts"] >= 1

@@ -193,3 +193,14 @@ def test_service_taskgraph_bit_identical_and_cached():
         assert np.array_equal(r3.pe_of, direct.pe_of)
     finally:
         svc.close()
+
+
+def test_to_graph_host_matches_device():
+    """host=True gives the same padded CSR as numpy arrays, unmemoized."""
+    tg = TaskGraph.from_edges(5, [0, 1, 2, 3], [1, 2, 3, 4], [1.0, 2.0, 3.0, 4.0])
+    dev, host = tg.to_graph(), tg.to_graph(host=True)
+    for a, b in zip(dev, host):
+        assert isinstance(b, np.ndarray) or np.isscalar(b)
+        assert np.asarray(a).dtype == np.asarray(b).dtype
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert tg.to_graph() is dev

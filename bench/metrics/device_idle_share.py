@@ -1,0 +1,9 @@
+"""Device (one v5e): the share of the traced window in which no operation
+ran on the device (``trace.py``: 1 - busy / window)."""
+
+
+def read(rec: dict):
+    tr = rec.get("trace")
+    if tr is None or tr.window_s <= 0 or not tr.device_ops:
+        return None
+    return 100.0 * (1.0 - tr.busy_s / tr.window_s)

@@ -2,6 +2,8 @@
 
     PYTHONPATH=src python examples/quickstart.py
 """
+import time
+
 import numpy as np
 
 from repro.compile_cache import use_compile_cache
@@ -24,13 +26,15 @@ def main():
     print(f"hierarchy {h} -> k={h.k} PEs")
 
     for strategy in ("naive", "bucket"):
+        t0 = time.perf_counter()
         res = shared_map(g, h, SharedMapConfig(
             eps=0.03, preset="eco", strategy=strategy, seed=0))
+        took = time.perf_counter() - t0  # pe_of is on the host: complete
         bw = np.bincount(res.pe_of, minlength=h.k)
         print(f"[{strategy:6s}] J = {res.J:12.0f}   "
               f"balance max/avg = {bw.max() / bw.mean():.3f}   "
               f"partition calls = {res.stats['partition_calls']}   "
-              f"time = {res.stats['seconds']:.1f}s")
+              f"time = {took:.1f}s")
 
     print(f"[random] J = {evaluate_J(g, h, random_mapping(g, h)):12.0f}")
     print(f"[identy] J = {evaluate_J(g, h, identity_mapping(g, h)):12.0f}")

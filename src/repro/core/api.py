@@ -9,12 +9,13 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 
 from .graph import Graph
 from .hierarchy import Hierarchy
 from .mapping import evaluate_J
-from .multisection import hierarchical_multisection
+from .multisection import hierarchical_multisection, new_request_id
 from .taskgraph import TaskGraph
 
 
@@ -31,9 +32,6 @@ class SharedMapConfig:
     backend: str = "auto"        # refinement kernels: auto | ell | xla
     # ("ell" = Pallas lp_gain kernels over the padded [N, DEG] adjacency;
     #  "auto" picks it whenever kernels.ops.kernel_backend() is live.)
-    coarsen_telemetry: bool = False  # fill stats["coarsen"] with the root
-    # graph's per-level cascade sizes (one extra device pass; the mapping
-    # itself is unchanged). See multisection.hierarchical_multisection.
     refine_mapping: bool = False  # optional block<->PE swap pass. The paper's
     # SharedMap deliberately has none (§6.4) — with a KaFFPa-strength
     # partitioner it is unnecessary. Our JAX substrate partitioner is weaker,
@@ -101,17 +99,24 @@ def shared_map_direct(g: Graph | TaskGraph, h: Hierarchy, cfg: SharedMapConfig,
     (None = strategy default): the service's shadow verifier passes
     ``resident=False`` to run a request on the bitwise host-ref twin of
     the device pipeline, and its worker processes forward the session's
-    device-quarantine decision the same way."""
-    if isinstance(g, TaskGraph):
-        g = g.to_graph()
-    res = hierarchical_multisection(
-        g, h, eps=cfg.eps, preset=cfg.preset, strategy=cfg.strategy,
-        seed=cfg.seed, adaptive=cfg.adaptive, backend=cfg.backend,
-        checkpoint=checkpoint, resident=resident,
-        coarsen_telemetry=cfg.coarsen_telemetry,
-    )
-    res.pe_of = finalize_mapping(g, h, cfg, res.pe_of, res.stats)
-    return SharedMapResult(pe_of=res.pe_of, J=evaluate_J(g, h, res.pe_of), stats=res.stats)
+    device-quarantine decision the same way.
+
+    The call is the trace span ``repro.map``; its finalize and J
+    evaluation are ``repro.finalize``. Both carry the request's ``req``
+    id, as the planner's spans inside do."""
+    req = new_request_id()
+    with jax.profiler.TraceAnnotation("repro.map", req=req):
+        if isinstance(g, TaskGraph):
+            g = g.to_graph()
+        res = hierarchical_multisection(
+            g, h, eps=cfg.eps, preset=cfg.preset, strategy=cfg.strategy,
+            seed=cfg.seed, adaptive=cfg.adaptive, backend=cfg.backend,
+            checkpoint=checkpoint, resident=resident, req=req,
+        )
+        with jax.profiler.TraceAnnotation("repro.finalize", req=req):
+            res.pe_of = finalize_mapping(g, h, cfg, res.pe_of, res.stats)
+            J = evaluate_J(g, h, res.pe_of)
+    return SharedMapResult(pe_of=res.pe_of, J=J, stats=res.stats)
 
 
 def finalize_mapping(g: Graph, h: Hierarchy, cfg: SharedMapConfig,

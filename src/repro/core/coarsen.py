@@ -308,8 +308,8 @@ def coarsen_once(g: Graph, salt=0, rounds: int = 3,
 def coarsen_cascade(g: Graph, levels: int, ell_deg: int | None = None,
                     rounds: int = 3):
     """Run the fused coarsening cascade alone and return per-level sizes
-    ``(ns [levels], ms [levels])`` — the telemetry behind
-    ``stats["coarsen"]`` and the large-instance benchmark tier. The scan
+    ``(ns [levels], ms [levels])`` — the large-instance benchmark tier's
+    telemetry, and the same sizes the fused v-cycle reports per lane. The scan
     carries ONLY the current graph (O(1) memory in ``levels``), so this
     path handles 10^6-vertex instances the full v-cycle's stacked
     uncoarsening arrays would not."""

@@ -13,6 +13,8 @@ routines implement the two-phase baselines:
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 import jax
@@ -21,6 +23,14 @@ import jax.numpy as jnp
 from .graph import Graph, edge_mask
 from .hierarchy import Hierarchy, mapping_cost, pe_distance
 from ..kernels import ops as kops
+
+
+@functools.partial(jax.jit, static_argnames=("N",))
+def _pad_pe(pe: jax.Array, N: int) -> jax.Array:
+    """``pe`` padded with PE 0 to the graph's N slots (padded edges weigh
+    0), under the J evaluation's ``evaluate`` scope (kernels/ops.py)."""
+    with jax.named_scope("evaluate"):
+        return jnp.concatenate([pe, jnp.zeros(N - pe.shape[0], jnp.int32)])
 
 
 def evaluate_J(g: Graph, h: Hierarchy, pe_of: np.ndarray,
@@ -40,7 +50,7 @@ def evaluate_J(g: Graph, h: Hierarchy, pe_of: np.ndarray,
             f"{int(g.n)} vertices (padded to N={g.N}); pass one PE id per "
             f"vertex of THIS graph")
     if pe.shape[0] < g.N:
-        pe = jnp.concatenate([pe, jnp.zeros(g.N - pe.shape[0], jnp.int32)])
+        pe = _pad_pe(pe, g.N)
     g_below = jnp.asarray((1,) + h.strides[:-1], jnp.int32)
     dvec = jnp.asarray(h.d, jnp.float32)
     return float(kops.mapcost(g.rows, g.cols, g.ewgt, pe, g_below, dvec,

@@ -81,11 +81,25 @@ Transfer accounting
 Module-level counters (:func:`transfer_stats` / :func:`reset_transfer_stats`)
 record every host<->device array movement the multisection performs:
 bulk graph uploads (`_stack_to_device`, `_partition_one`), bulk label /
-mirror fetches (``d2h_array_fetches``) and per-level metadata fetches
-(``d2h_meta_fetches``). On the ``device`` strategy a request costs exactly
-ONE array fetch — the final ``pe_of`` — which the ``device_pipeline``
-benchmark and tests assert. (On CPU hosts the "transfer" is a copy; the
-counters measure the protocol an accelerator would pay.)
+mirror fetches (``d2h_array_fetches``), per-level metadata fetches
+(``d2h_meta_fetches``) and the one fetch of the v-cycle counter after the
+mapping is complete (``d2h_counter_fetches``). On the ``device`` strategy
+a request costs exactly ONE array fetch — the final ``pe_of`` — which the
+``device_pipeline`` benchmark and tests assert. (On CPU hosts the
+"transfer" is a copy; the counters measure the protocol an accelerator
+would pay.)
+
+Tracing
+-------
+The planner's host boundaries are ``jax.profiler.TraceAnnotation`` spans
+(``repro.plan``, ``repro.dispatch``, ``repro.advance``, ``repro.fetch``;
+``core/api.py`` adds ``repro.map`` and ``repro.finalize``), each with the
+request's ``req`` id and, where they apply, ``depth`` and ``lanes``. The
+device programs run under ``jax.named_scope``s: ``level_ops`` here,
+``coarsen``/``initial``/``refine``/``select`` in ``core/partition.py``,
+``evaluate`` around the J evaluation. Both cost nothing while no profiler
+runs, and a profiler trace puts each device op and each idle gap down to
+one of them.
 
 All strategies use salts derived from the subgraph's position in the
 hierarchy (not traversal order), so results are reproducible per strategy
@@ -98,8 +112,8 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+import itertools
 import threading
-import time
 from typing import Callable
 
 import jax
@@ -109,8 +123,8 @@ import numpy as np
 from .graph import (Graph, assemble_padded, default_ell_deg,
                     padded_csr_indptr, repad_device, split_blocks, take_lanes)
 from .hierarchy import Hierarchy, adaptive_epsilon, adaptive_epsilon_jnp
-from .partition import (batched_partition, clear_batched_partition_cache,
-                        num_levels, partition)
+from .partition import (BatchedPartition, Preset, batched_partition,
+                        clear_batched_partition_cache, num_levels, partition)
 from .refine import resolve_backend
 from ..kernels import ops as kops
 
@@ -125,7 +139,8 @@ _XFER_LOCK = threading.Lock()
 def _zero_xfer() -> dict:
     return {"h2d_bytes": 0, "h2d_transfers": 0,
             "d2h_bytes": 0, "d2h_array_fetches": 0,
-            "d2h_meta_bytes": 0, "d2h_meta_fetches": 0}
+            "d2h_meta_bytes": 0, "d2h_meta_fetches": 0,
+            "d2h_counter_bytes": 0, "d2h_counter_fetches": 0}
 
 
 _XFER = _zero_xfer()
@@ -372,6 +387,14 @@ class _LaneRef:
     wsum: float = 0.0
 
 
+def _level_op(key: tuple, fn: Callable) -> Callable:
+    """:func:`_jit_op` of ``fn`` traced under the ``level_ops`` scope."""
+    def run(*args):
+        with jax.named_scope("level_ops"):
+            return fn(*args)
+    return _jit_op(key, run)
+
+
 def _root_op(Ns: int, Ms: int, N0: int, M0: int) -> Callable:
     """g -> ([1,...] repadded batch, [1, N0] orig ids, f32 total weight)."""
     def run(g: Graph):
@@ -380,7 +403,22 @@ def _root_op(Ns: int, Ms: int, N0: int, M0: int) -> Callable:
         orig = jnp.where(ar < g2.n, ar, g2.n)  # sentinel = n (spare pe slot)
         batch = jax.tree_util.tree_map(lambda a: a[None], g2)
         return batch, orig[None], jnp.sum(g2.vwgt)
-    return _jit_op(("root", Ns, Ms, N0, M0), run)
+    return _level_op(("root", Ns, Ms, N0, M0), run)
+
+
+def _zeros_op(n: int) -> Callable:
+    """() -> [n] i32 zeros: the resident pe buffer."""
+    return _level_op(("zeros", n), lambda: jnp.zeros(n, jnp.int32))
+
+
+def _concat_op(N: int, M: int, lanes: tuple[int, ...]) -> Callable:
+    """Stack per-container [B_i, ...] batches and [B_i, N] id views into
+    one dispatch batch."""
+    def run(batches, origs):
+        cat = lambda *a: jnp.concatenate(a, axis=0)
+        return (jax.tree_util.tree_map(cat, *batches),
+                jnp.concatenate(origs, axis=0))
+    return _level_op(("concat", N, M, lanes), run)
 
 
 def _split_op(B: int, N: int, M: int, arity: int) -> Callable:
@@ -391,7 +429,7 @@ def _split_op(B: int, N: int, M: int, arity: int) -> Callable:
         )(gb, parts, ob)
         flat = lambda a: a.reshape((B * arity,) + a.shape[2:])
         return (jax.tree_util.tree_map(flat, ch), flat(co), flat(ws))
-    return _jit_op(("split", B, N, M, arity, kops.kernel_backend()), run)
+    return _level_op(("split", B, N, M, arity, kops.kernel_backend()), run)
 
 
 def _gather_op(Ns: int, Ms: int, Nd: int, Md: int, nsel: int) -> Callable:
@@ -407,7 +445,7 @@ def _gather_op(Ns: int, Ms: int, Nd: int, Md: int, nsel: int) -> Callable:
             pad = jnp.broadcast_to(sent, (nsel, Nd - Ns)).astype(jnp.int32)
             o = jnp.concatenate([o, pad], axis=1)
         return sub, o
-    return _jit_op(("gather", Ns, Ms, Nd, Md, nsel), run)
+    return _level_op(("gather", Ns, Ms, Nd, Md, nsel), run)
 
 
 def _eps_op(B: int, k: int, k_sub: int, depth: int, eps: float,
@@ -421,7 +459,8 @@ def _eps_op(B: int, k: int, k_sub: int, depth: int, eps: float,
         if not adaptive or depth <= 0:
             return jnp.full((B,), eps, jnp.float32)
         return adaptive_epsilon_jnp(eps, total, wsums, k, k_sub, depth)
-    return _jit_op(("eps", B, k, k_sub, depth, float(eps), bool(adaptive)), run)
+    return _level_op(("eps", B, k, k_sub, depth, float(eps), bool(adaptive)),
+                     run)
 
 
 def _scatter_op(B: int, N: int) -> Callable:
@@ -430,7 +469,7 @@ def _scatter_op(B: int, N: int) -> Callable:
     def run(pe, ob, parts, bases):
         vals = bases[:, None] + parts[:, :N].astype(jnp.int32)
         return pe.at[ob.reshape(-1)].set(vals.reshape(-1), mode="drop")
-    return _jit_op(("scatter", B, N), run)
+    return _level_op(("scatter", B, N), run)
 
 
 # ---------------------------------------------------------------------------
@@ -447,7 +486,10 @@ class PlanGroup:
     level's on-device children) plus the [B, N] original-id view; their
     ``eps`` may live on device (``eps_dev``) for the ``device`` strategy.
     ``eps``/``salts`` are per-member (position-derived, so independent of
-    which batch the member eventually rides in).
+    which batch the member eventually rides in). ``req`` is the id of the
+    request the group belongs to (trace spans only); ``level_sizes`` is set
+    by the dispatch: the dispatch's ``[B', levels]`` coarse vertex counts
+    on device and this group's first row in it.
     """
 
     members: list
@@ -464,6 +506,8 @@ class PlanGroup:
     batch: Graph | None = None           # [B, ...] device input (resident)
     batch_orig: jax.Array | None = None  # [B, N] root ids (resident)
     eps_dev: jax.Array | None = None     # [B] f32 device eps (device strategy)
+    req: int = 0
+    level_sizes: tuple[jax.Array, int] | None = None
 
     @property
     def exec_key(self) -> tuple:
@@ -544,27 +588,39 @@ def dispatch_group_batch(groups: list[PlanGroup], cache_stats: dict,
     g0 = groups[0]
     B = sum(len(gr.members) for gr in groups)
     Bp = _next_pow2(B) if pad_batch_pow2 else B
-    _note_program(g0.N, g0.M, Bp, g0.arity, g0.levels, g0.preset, g0.backend,
-                  g0.deg, cache_stats)
-    fn = batched_partition(g0.arity, g0.levels, g0.preset, g0.backend, g0.deg)
+    reqs = sorted({gr.req for gr in groups})
+    with jax.profiler.TraceAnnotation(
+            "repro.dispatch", req=",".join(map(str, reqs)),
+            depth=g0.members[0].depth, lanes=Bp):
+        _note_program(g0.N, g0.M, Bp, g0.arity, g0.levels, g0.preset,
+                      g0.backend, g0.deg, cache_stats)
+        fn = batched_partition(g0.arity, g0.levels, g0.preset, g0.backend,
+                               g0.deg)
 
-    batches = [gr.graph_batch() for gr in groups]
-    eps_parts = [gr.eps_array() for gr in groups]
-    salt_parts = [gr.salts_array() for gr in groups]
-    if len(groups) == 1:
-        batch, eps, salts = batches[0], eps_parts[0], salt_parts[0]
-    else:
-        cat = lambda xs: jnp.concatenate(xs, axis=0)
-        batch = jax.tree_util.tree_map(lambda *a: cat(a), *batches)
-        eps = cat(eps_parts)
-        salts = cat(salt_parts)
-    if Bp > B:
-        rep = lambda a: jnp.concatenate(
-            [a, jnp.repeat(a[-1:], Bp - B, axis=0)], axis=0)
-        batch = jax.tree_util.tree_map(rep, batch)
-        eps = rep(eps)
-        salts = rep(salts)
-    parts = fn(batch, eps, salts)
+        batches = [gr.graph_batch() for gr in groups]
+        eps_parts = [gr.eps_array() for gr in groups]
+        salt_parts = [gr.salts_array() for gr in groups]
+        if len(groups) == 1:
+            batch, eps, salts = batches[0], eps_parts[0], salt_parts[0]
+        else:
+            cat = lambda xs: jnp.concatenate(xs, axis=0)
+            batch = jax.tree_util.tree_map(lambda *a: cat(a), *batches)
+            eps = cat(eps_parts)
+            salts = cat(salt_parts)
+        if Bp > B:
+            rep = lambda a: jnp.concatenate(
+                [a, jnp.repeat(a[-1:], Bp - B, axis=0)], axis=0)
+            batch = jax.tree_util.tree_map(rep, batch)
+            eps = rep(eps)
+            salts = rep(salts)
+        if isinstance(fn, BatchedPartition):
+            parts, sizes = fn.with_level_sizes(batch, eps, salts)
+        else:  # a stand-in callable gives the parts alone
+            parts, sizes = fn(batch, eps, salts), None
+    ofs = 0
+    for gr in groups:
+        gr.level_sizes = None if sizes is None else (sizes, ofs)
+        ofs += len(gr.members)
     return parts, groups
 
 
@@ -605,6 +661,12 @@ def execute_group_batch(groups: list[PlanGroup], cache_stats: dict,
 
 
 _PLANNER_STRATEGIES = ("layer", "bucket", "device")
+_REQUEST_IDS = itertools.count(1)
+
+
+def new_request_id() -> int:
+    """A process-unique id for one mapping request's trace spans."""
+    return next(_REQUEST_IDS)
 
 
 class LevelPlanner:
@@ -625,13 +687,22 @@ class LevelPlanner:
     weight fetch on bucket/layer (their bucket shapes are data-dependent).
     ``resident=False`` is the PR-5 host-mirror loop, planning-identical
     and bit-identical in its results (the regression reference).
+
+    ``req`` names the request in the planner's trace spans (a fresh id from
+    :func:`new_request_id` by default). ``result()`` adds the v-cycle
+    counter to the stats: ``vcycle_real_vertex_work``, the vertices of
+    every graph the partition calls' v-cycles processed (each lane's graph,
+    its coarse graphs, and the graph again for each finest-level v-cycle),
+    and ``vcycle_padded_vertex_work``, the vertex slots those passes ran
+    at (each lane's padded N per pass).
     """
 
     def __init__(self, g: Graph, h: Hierarchy, eps: float = 0.03,
                  preset: str = "eco", seed: int = 0, adaptive: bool = True,
                  backend: str = "auto", bucketed: bool = True,
                  checkpoint: Callable[[], None] | None = None,
-                 strategy: str | None = None, resident: bool | None = None):
+                 strategy: str | None = None, resident: bool | None = None,
+                 req: int | None = None):
         if strategy is None:
             strategy = "bucket" if bucketed else "layer"
         if strategy not in _PLANNER_STRATEGIES:
@@ -652,8 +723,9 @@ class LevelPlanner:
                       "backend": self.backend,
                       "compile_cache": {"hits": 0, "misses": 0}}
         self.cache_stats = self.stats["compile_cache"]
-        self._t0 = time.time()
-        self._level_t0: float | None = None
+        self.req = new_request_id() if req is None else int(req)
+        # per dispatched group: (level sizes, first row, lane sizes, N, levels)
+        self._vcycle: list[tuple] = []
         self._groups: list[PlanGroup] | None = None
         self._done = False
         self._work: list = []
@@ -687,7 +759,7 @@ class LevelPlanner:
         batch, orig, tw = _root_op(g.N, g.M, self.N0, self.M0)(g)
         root_level = _DeviceLevel(g=batch, orig=orig, depth=self.h.l)
         self._sent = batch.n[0]          # spare pe slot for pad writes
-        self._pe = jnp.zeros(n_root + 1, jnp.int32)
+        self._pe = _zeros_op(n_root + 1)()
         self._tw_dev = tw
         self._root_deg = None
         if self.backend == "ell":
@@ -731,20 +803,25 @@ class LevelPlanner:
                         self.pe_of[hg.orig_ids] = hg.pe_base
             self._work = [w for w in self._current if w.depth > 0]
             if not self._work:
-                self._finish()
+                self._done = True
                 return []
-            self._level_t0 = time.time()
-            if self.strategy == "device":
-                self._groups = self._plan_root_shape()
-            else:
-                self._groups = plan_level(
-                    self._work, self.h, self.eps, self.preset, self.seed,
-                    self.total_weight, self.adaptive, self.backend,
-                    self.bucketed)
-                if self.resident:
-                    for gr in self._groups:
-                        gr.resident = True
-                        gr.batch, gr.batch_orig = self._gather_group(gr)
+            with jax.profiler.TraceAnnotation(
+                    "repro.plan", req=self.req, depth=self._work[0].depth,
+                    lanes=len(self._work)):
+                if self.strategy == "device":
+                    groups = self._plan_root_shape()
+                else:
+                    groups = plan_level(
+                        self._work, self.h, self.eps, self.preset, self.seed,
+                        self.total_weight, self.adaptive, self.backend,
+                        self.bucketed)
+                    if self.resident:
+                        for gr in groups:
+                            gr.resident = True
+                            gr.batch, gr.batch_orig = self._gather_group(gr)
+                for gr in groups:
+                    gr.req = self.req
+            self._groups = groups
         return self._groups
 
     def _plan_root_shape(self) -> list[PlanGroup]:
@@ -802,28 +879,43 @@ class LevelPlanner:
             i = j
         if len(batches) == 1:
             return batches[0], origs[0]
-        cat = lambda *a: jnp.concatenate(a, axis=0)
-        return (jax.tree_util.tree_map(cat, *batches),
-                jnp.concatenate(origs, axis=0))
+        lanes = tuple(int(o.shape[0]) for o in origs)
+        return _concat_op(gr.N, gr.M, lanes)(batches, origs)
 
     def advance(self, results: list) -> None:
         """Feed one ``[B_i, N]`` partition array per group from ``plan()``."""
         groups = self.plan()
         if len(results) != len(groups):
             raise ValueError(f"expected {len(groups)} results, got {len(results)}")
-        if self.resident:
-            self._advance_resident(groups, results)
-        else:
-            nxt: list[_HostGraph] = []
-            for gr, parts in zip(groups, results):
-                parts = np.asarray(parts)
-                for i, hg in enumerate(gr.members):
-                    self._record(gr.N, hg.n)
-                    nxt.extend(_children_of(hg, parts[i][: hg.n], self.h))
-            self._current = nxt
-        self.stats["levels"].append(
-            {"graphs": len(self._work), "seconds": time.time() - self._level_t0})
+        with jax.profiler.TraceAnnotation(
+                "repro.advance", req=self.req, depth=self._work[0].depth,
+                lanes=len(self._work)):
+            for gr in groups:
+                self._note_vcycle(gr)
+            if self.resident:
+                self._advance_resident(groups, results)
+            else:
+                nxt: list[_HostGraph] = []
+                for gr, parts in zip(groups, results):
+                    parts = np.asarray(parts)
+                    for i, hg in enumerate(gr.members):
+                        self._record(gr.N, hg.n)
+                        nxt.extend(_children_of(hg, parts[i][: hg.n], self.h))
+                self._current = nxt
+        self.stats["levels"].append({"graphs": len(self._work)})
         self._groups = None
+
+    def _note_vcycle(self, gr: PlanGroup) -> None:
+        """Keep a dispatched group's v-cycle sizes, on device, for the
+        counter ``result()`` sums."""
+        if gr.level_sizes is None or gr.arity == 1:
+            return  # no sizes came back, or no v-cycle ran (k = 1)
+        sizes, first = gr.level_sizes
+        if gr.members[0].n < 0:  # device-strategy lanes: sizes on device
+            ns = gr.batch.n
+        else:
+            ns = [m.n for m in gr.members]
+        self._vcycle.append((sizes, first, ns, gr.N, gr.levels))
 
     def _advance_resident(self, groups: list[PlanGroup], results: list) -> None:
         nxt: list[_LaneRef] = []
@@ -880,20 +972,38 @@ class LevelPlanner:
         self.stats["padded_vertex_work"] += int(batchN)
         self.stats["real_vertex_work"] += int(realn)
 
-    def _finish(self) -> None:
-        if not self._done:
-            self._done = True
-            self.stats["seconds"] = time.time() - self._t0
-
     def result(self) -> "MultisectionResult":
         if not self._done:
             raise RuntimeError("planner has pending levels")
         if self.resident and self.pe_of is None:
             # THE device->host sync point: one fetch per request.
-            pe = np.asarray(self._pe[: self.n_root])
+            with jax.profiler.TraceAnnotation("repro.fetch", req=self.req):
+                pe = np.asarray(self._pe[: self.n_root])
             _acct(d2h_bytes=pe.nbytes, d2h_array_fetches=1)
             self.pe_of = pe
+        if self._vcycle:
+            self._count_vcycle()
         return MultisectionResult(pe_of=self.pe_of, stats=self.stats)
+
+    def _count_vcycle(self) -> None:
+        """Fetch every dispatch's level sizes at once, after the mapping is
+        complete, and sum them into the v-cycle counter."""
+        on_device = [(v[0], v[2]) for v in self._vcycle]
+        _acct(d2h_counter_bytes=sum(a.nbytes for pair in on_device
+                                    for a in pair if isinstance(a, jax.Array)),
+              d2h_counter_fetches=1)
+        fetched = jax.device_get(on_device)
+        passes = 1 + Preset.get(self.preset).vcycles
+        real = padded = 0
+        for (sizes, ns), (_, first, _, N, levels) in zip(fetched,
+                                                         self._vcycle):
+            ns = np.asarray(ns, np.int64)
+            lanes = np.asarray(sizes, np.int64)[first: first + ns.shape[0]]
+            real += int(ns.sum()) * passes + int(lanes.sum())
+            padded += ns.shape[0] * N * (passes + levels)
+        self.stats["vcycle_real_vertex_work"] = real
+        self.stats["vcycle_padded_vertex_work"] = padded
+        self._vcycle = []
 
 
 # ---------------------------------------------------------------------------
@@ -934,30 +1044,6 @@ def _partition_one(hg: _HostGraph, k: int, eps_val: float, preset: str,
     return part[: hg.n]
 
 
-def _coarsen_telemetry_stats(g: Graph, h: Hierarchy) -> dict:
-    """``stats["coarsen"]``: per-level shrink telemetry of the ROOT graph's
-    coarsening cascade (the depth/degree the first sub-partition uses),
-    measured with :func:`coarsen.coarsen_cascade` — O(1) device memory in
-    the level count, one ``2*levels``-scalar fetch."""
-    from .coarsen import coarsen_cascade
-    n, m = int(g.n), int(g.m)
-    arity = h.a[h.l - 1] if h.l > 0 else h.k
-    lv = num_levels(n, arity)
-    deg = default_ell_deg(n, max(m, 1))
-    ns, ms = coarsen_cascade(g, lv, ell_deg=deg)
-    ns = np.asarray(ns)
-    ms = np.asarray(ms)
-    _acct(d2h_meta_bytes=ns.nbytes + ms.nbytes, d2h_meta_fetches=2)
-    per = []
-    prev = n
-    for i in range(lv):
-        ni = int(ns[i])
-        per.append({"n": ni, "m": int(ms[i]),
-                    "shrink": round(prev / max(ni, 1), 4)})
-        prev = ni
-    return {"levels": lv, "ell_deg": deg, "rounds": 3, "per_level": per}
-
-
 def hierarchical_multisection(
     g: Graph,
     h: Hierarchy,
@@ -969,7 +1055,7 @@ def hierarchical_multisection(
     backend: str = "auto",
     checkpoint: Callable[[], None] | None = None,
     resident: bool | None = None,
-    coarsen_telemetry: bool = False,
+    req: int | None = None,
 ) -> MultisectionResult:
     """Partition ``g`` along ``h`` and return the (identity) mapping.
 
@@ -979,30 +1065,23 @@ def hierarchical_multisection(
     ``resident`` applies to the planner strategies (layer/bucket/device):
     ``None``/``True`` keeps the level loop on device, ``False`` forces the
     host-mirror reference loop (bit-identical results either way).
-    ``coarsen_telemetry`` additionally runs the root graph's coarsening
-    cascade for its per-level sizes (``stats["coarsen"]``; costs one extra
-    device pass, never changes the mapping).
+    ``req`` names the request in the planner's trace spans.
     """
     backend = resolve_backend(backend)
-    coarsen_stats = (_coarsen_telemetry_stats(g, h)
-                     if coarsen_telemetry else None)
     if strategy in _PLANNER_STRATEGIES:
         # the planner path: identical planning to serve/mapper, each group
         # executed alone (no cross-request members to coalesce here).
         planner = LevelPlanner(g, h, eps=eps, preset=preset, seed=seed,
                                adaptive=adaptive, backend=backend,
                                strategy=strategy, resident=resident,
-                               checkpoint=checkpoint)
+                               checkpoint=checkpoint, req=req)
         while True:
             groups = planner.plan()
             if not groups:
                 break
             planner.advance([execute_group_batch([gr], planner.cache_stats)[0]
                              for gr in groups])
-        res = planner.result()
-        if coarsen_stats is not None:
-            res.stats["coarsen"] = coarsen_stats
-        return res
+        return planner.result()
     if strategy not in ("naive", "queue"):
         raise ValueError(f"unknown strategy {strategy!r}")
     if resident is not None:
@@ -1021,8 +1100,6 @@ def hierarchical_multisection(
              "padded_vertex_work": 0, "real_vertex_work": 0,
              "backend": backend,
              "compile_cache": {"hits": 0, "misses": 0}}
-    if coarsen_stats is not None:
-        stats["coarsen"] = coarsen_stats
     cache_stats = stats["compile_cache"]
     rec_lock = threading.Lock()
 
@@ -1035,7 +1112,6 @@ def hierarchical_multisection(
     ctx = (h, eps, preset, seed, total_weight, adaptive, backend, record,
            cache_stats, checkpoint)
     current = [root]
-    t0 = time.time()
     while current:
         if checkpoint is not None:
             checkpoint()
@@ -1046,15 +1122,13 @@ def hierarchical_multisection(
         work = [hg for hg in current if hg.depth > 0]
         if not work:
             break
-        lvl_t0 = time.time()
         if strategy == "naive":
             produced = _run_naive(work, ctx)
         else:
             produced = _run_queue(work, ctx)
-        stats["levels"].append({"graphs": len(work), "seconds": time.time() - lvl_t0})
+        stats["levels"].append({"graphs": len(work)})
         nxt.extend(produced)
         current = nxt
-    stats["seconds"] = time.time() - t0
     return MultisectionResult(pe_of=pe_of, stats=stats)
 
 

@@ -78,8 +78,14 @@ def num_levels(n: int, k: int, coarse_factor: int = 24,
 def _partition_single(
     g: Graph, k: int, eps: jax.Array, levels: int, preset: Preset, salt: jax.Array,
     backend: str = "auto", ell_deg: int | None = None, coarsen: str = "ell",
-) -> jax.Array:
-    """One seeded multilevel run; all shapes stay (N, M).
+) -> tuple[jax.Array, jax.Array]:
+    """One seeded multilevel run; all shapes stay (N, M). Returns the
+    partition and the ``[levels]`` i32 vertex counts of the coarse graphs,
+    level 1 first.
+
+    The stages run under ``jax.named_scope``s (``coarsen``, ``initial``,
+    ``refine``), so every op's ``op_name`` metadata, and with it a profiler
+    trace, says which stage it belongs to.
 
     ``coarsen="ell"`` (default) is the fused v-cycle: coarsening runs
     through the ELL kernels and both the downward (coarsen) and upward
@@ -94,49 +100,51 @@ def _partition_single(
     total = g.total_weight()
     Lmax = (1.0 + eps) * total / k
 
+    def initial(coarsest):
+        with jax.named_scope("initial"):
+            return initial_partition(
+                coarsest, k, Lmax, salt=salt,
+                polish_rounds=preset.coarsest_polish,
+                backend=backend, ell_deg=ell_deg)
+
     if levels == 0:
-        part = initial_partition(
-            g, k, Lmax, salt=salt, polish_rounds=preset.coarsest_polish,
-            backend=backend, ell_deg=ell_deg)
+        sizes = jnp.zeros((0,), jnp.int32)
+        part = initial(g)
     elif coarsen == "segment":
         graphs = [g]
         maps = []
         cur = g
-        for lvl in range(levels):
-            cur, newid = coarsen_once(cur, salt=(lvl + 1) * 131 + 7)
-            graphs.append(cur)
-            maps.append(newid)
-        part = initial_partition(
-            graphs[-1], k, Lmax, salt=salt,
-            polish_rounds=preset.coarsest_polish,
-            backend=backend, ell_deg=ell_deg,
-        )
-        for lvl in range(levels - 1, -1, -1):
-            part = part[maps[lvl]]  # project to finer level
-            part = lp_refine(
-                graphs[lvl], part, k, Lmax, rounds=preset.refine_rounds,
-                salt=salt + 1000 + lvl, backend=backend, ell_deg=ell_deg,
-            )
-            part = rebalance(graphs[lvl], part, k, Lmax, rounds=4,
-                             salt=salt + 2000 + lvl, backend=backend,
-                             ell_deg=ell_deg)
+        with jax.named_scope("coarsen"):
+            for lvl in range(levels):
+                cur, newid = coarsen_once(cur, salt=(lvl + 1) * 131 + 7)
+                graphs.append(cur)
+                maps.append(newid)
+            sizes = jnp.stack([c.n for c in graphs[1:]]).astype(jnp.int32)
+        part = initial(graphs[-1])
+        with jax.named_scope("refine"):
+            for lvl in range(levels - 1, -1, -1):
+                part = part[maps[lvl]]  # project to finer level
+                part = lp_refine(
+                    graphs[lvl], part, k, Lmax, rounds=preset.refine_rounds,
+                    salt=salt + 1000 + lvl, backend=backend, ell_deg=ell_deg,
+                )
+                part = rebalance(graphs[lvl], part, k, Lmax, rounds=4,
+                                 salt=salt + 2000 + lvl, backend=backend,
+                                 ell_deg=ell_deg)
     else:
         # static DEG cap for the coarsening kernels; reuse the refinement
         # cap when the ELL refinement backend pinned one
         deg_c = ell_deg if ell_deg is not None else default_ell_deg(g.N, g.M)
-        csalts = (jnp.arange(levels, dtype=jnp.int32) + 1) * 131 + 7
 
         def down(cur, sl):
             gc, newid = coarsen_once(cur, salt=sl, ell_deg=deg_c)
-            return gc, (cur, newid)   # emit the FINE graph of this level
+            # emit the FINE graph of this level and the coarse size
+            return gc, (cur, newid, gc.n.astype(jnp.int32))
 
-        coarsest, (fines, maps) = jax.lax.scan(down, g, csalts)
-        part = initial_partition(
-            coarsest, k, Lmax, salt=salt,
-            polish_rounds=preset.coarsest_polish,
-            backend=backend, ell_deg=ell_deg,
-        )
-        lvls = jnp.arange(levels, dtype=jnp.int32)
+        with jax.named_scope("coarsen"):
+            csalts = (jnp.arange(levels, dtype=jnp.int32) + 1) * 131 + 7
+            coarsest, (fines, maps, sizes) = jax.lax.scan(down, g, csalts)
+        part = initial(coarsest)
 
         def up(part, x):
             gf, mp, lvl = x
@@ -149,14 +157,48 @@ def _partition_single(
                              ell_deg=ell_deg)
             return part, None
 
-        part, _ = jax.lax.scan(up, part, (fines, maps, lvls), reverse=True)
+        with jax.named_scope("refine"):
+            lvls = jnp.arange(levels, dtype=jnp.int32)
+            part, _ = jax.lax.scan(up, part, (fines, maps, lvls),
+                                   reverse=True)
 
-    for cyc in range(preset.vcycles):
-        part = lp_refine(g, part, k, Lmax, rounds=preset.refine_rounds,
-                         salt=salt + 3000 + cyc, backend=backend, ell_deg=ell_deg)
-        part = rebalance(g, part, k, Lmax, rounds=4, salt=salt + 4000 + cyc,
-                         backend=backend, ell_deg=ell_deg)
-    return part
+    with jax.named_scope("refine"):
+        for cyc in range(preset.vcycles):
+            part = lp_refine(g, part, k, Lmax, rounds=preset.refine_rounds,
+                             salt=salt + 3000 + cyc, backend=backend,
+                             ell_deg=ell_deg)
+            part = rebalance(g, part, k, Lmax, rounds=4,
+                             salt=salt + 4000 + cyc, backend=backend,
+                             ell_deg=ell_deg)
+    return part, sizes
+
+
+def _best_of_restarts(g: Graph, k: int, eps: jax.Array, levels: int,
+                      preset_name: str, salt, backend: str,
+                      ell_deg: int | None,
+                      coarsen: str) -> tuple[jax.Array, jax.Array]:
+    """:func:`partition`'s body: the best restart's partition and the
+    ``[levels]`` coarse vertex counts of its v-cycle."""
+    preset = Preset.get(preset_name)
+    salt = jnp.asarray(salt, jnp.int32)
+    if k == 1:
+        return jnp.zeros((g.N,), jnp.int32), jnp.zeros((levels,), jnp.int32)
+
+    salts = salt * 131 + jnp.arange(preset.restarts, dtype=jnp.int32) * 7919
+
+    def run(s):
+        p, sizes = _partition_single(g, k, eps, levels, preset, s, backend,
+                                     ell_deg, coarsen)
+        with jax.named_scope("select"):
+            cut = edge_cut(g, p)
+            Lmax = (1.0 + eps) * g.total_weight() / k
+            over = jnp.maximum(block_weights(g, p, k) - Lmax, 0.0).sum()
+            return p, sizes, cut + 1e6 * over
+
+    parts, sizes, scores = jax.vmap(run)(salts)
+    with jax.named_scope("select"):
+        best = jnp.argmin(scores)
+        return parts[best], sizes[best]
 
 
 @functools.partial(
@@ -185,32 +227,33 @@ def partition(
     is the fused kernel v-cycle, ``"segment"`` the seed's unrolled
     segment-reduction path (see ``_partition_single``).
     """
-    preset = Preset.get(preset_name)
-    salt = jnp.asarray(salt, jnp.int32)
-    if k == 1:
-        return jnp.zeros((g.N,), jnp.int32)
-
-    salts = salt * 131 + jnp.arange(preset.restarts, dtype=jnp.int32) * 7919
-
-    def run(s):
-        p = _partition_single(g, k, eps, levels, preset, s, backend, ell_deg,
-                              coarsen)
-        cut = edge_cut(g, p)
-        Lmax = (1.0 + eps) * g.total_weight() / k
-        over = jnp.maximum(block_weights(g, p, k) - Lmax, 0.0).sum()
-        return p, cut + 1e6 * over
-
-    parts, scores = jax.vmap(run)(salts)
-    best = jnp.argmin(scores)
-    return parts[best]
+    return _best_of_restarts(g, k, eps, levels, preset_name, salt, backend,
+                             ell_deg, coarsen)[0]
 
 
-_BATCHED_CACHE: dict[tuple, Callable] = {}
+class BatchedPartition:
+    """A jitted vmapped partition: calling it gives the ``[B, N]`` parts;
+    :meth:`with_level_sizes` also gives the ``[B, levels]`` i32 vertex
+    counts of each lane's coarse graphs (the winning restart's), from the
+    same program and without a transfer."""
+
+    def __init__(self, fn: Callable):
+        self._fn = fn
+
+    def __call__(self, gs: Graph, eps: jax.Array, salts: jax.Array):
+        return self._fn(gs, eps, salts)[0]
+
+    def with_level_sizes(self, gs: Graph, eps: jax.Array, salts: jax.Array):
+        return self._fn(gs, eps, salts)
+
+
+_BATCHED_CACHE: dict[tuple, BatchedPartition] = {}
 _BATCHED_LOCK = threading.Lock()
 
 
 def batched_partition(k: int, levels: int, preset: str, backend: str,
-                      ell_deg: int | None, coarsen: str = "ell") -> Callable:
+                      ell_deg: int | None,
+                      coarsen: str = "ell") -> BatchedPartition:
     """Memoized jitted vmapped partition callable ``(gs, eps, salts) ->
     [B, N] parts`` — the dispatch unit of every bucket/layer/device-level
     partition call (one executable per static key, shared process-wide
@@ -233,10 +276,10 @@ def batched_partition(k: int, levels: int, preset: str, backend: str,
     with _BATCHED_LOCK:
         fn = _BATCHED_CACHE.get(key)
         if fn is None:
-            fn = jax.jit(lambda gs, ee, ss: jax.vmap(
-                lambda g1, e1, s1: partition(g1, k, e1, levels, preset, s1,
-                                             backend, ell_deg, coarsen)
-            )(gs, ee, ss))
+            fn = BatchedPartition(jax.jit(lambda gs, ee, ss: jax.vmap(
+                lambda g1, e1, s1: _best_of_restarts(
+                    g1, k, e1, levels, preset, s1, backend, ell_deg, coarsen)
+            )(gs, ee, ss)))
             _BATCHED_CACHE[key] = fn
     return fn
 

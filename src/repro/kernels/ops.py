@@ -66,8 +66,21 @@ def dispatch(use_pallas: bool | None = None) -> tuple[bool, bool]:
     return use_pallas, backend == "interpret"
 
 
-_mapcost_partials_ref = jax.jit(ref.mapcost_partials_ref)
-_fold_partials = jax.jit(ref.fold_partials)
+def _evaluating(fn):
+    """``fn`` traced under the ``evaluate`` scope, so that a profiler trace
+    puts the J evaluation's device ops down to it. A scope around an eager
+    call does not reach the ops of the programs it runs: it has to be
+    inside the traced function."""
+    def run(*args, **kwargs):
+        with jax.named_scope("evaluate"):
+            return fn(*args, **kwargs)
+    return run
+
+
+_mapcost_partials_ref = jax.jit(_evaluating(ref.mapcost_partials_ref))
+_mapcost_partials_pallas = jax.jit(_evaluating(mapcost_pallas),
+                                   static_argnames=("interpret",))
+_fold_partials = jax.jit(_evaluating(ref.fold_partials))
 
 
 def mapcost(rows, cols, ewgt, pe_of, g_below, dvec, use_pallas: bool | None = None):
@@ -78,8 +91,8 @@ def mapcost(rows, cols, ewgt, pe_of, g_below, dvec, use_pallas: bool | None = No
     """
     use_pallas, interpret = dispatch(use_pallas)
     if use_pallas:
-        part = mapcost_pallas(rows, cols, ewgt, pe_of, g_below, dvec,
-                              interpret=interpret)
+        part = _mapcost_partials_pallas(rows, cols, ewgt, pe_of, g_below,
+                                        dvec, interpret=interpret)
     else:
         part = _mapcost_partials_ref(rows, cols, ewgt, pe_of, g_below, dvec)
     return _fold_partials(part)

@@ -63,6 +63,27 @@ def test_strategy_determinism(g):
     assert np.array_equal(a.pe_of, b.pe_of)
 
 
+# sha256 (first 16 hex digits) of the int32 pe_of bytes on g, recorded on
+# XLA's CPU backend from the program before its stages ran under
+# jax.named_scope: scopes change op metadata, never a mapping.
+PARENT_MAPPINGS = {
+    ("fast", "bucket", 0): "e6c5d6267fcb5602",
+    ("fast", "bucket", 4): "6c67c96d8b87879b",
+    ("fast", "device", 0): "e79ab9fe1bdc0a05",
+    ("eco", "bucket", 0): "9975672e84ef1cdc",
+}
+
+
+@pytest.mark.parametrize("preset,strategy,seed", list(PARENT_MAPPINGS))
+def test_mappings_bitwise_as_recorded(g, preset, strategy, seed):
+    import hashlib
+    res = shared_map(g, H_PAPER, SharedMapConfig(
+        eps=0.03, preset=preset, strategy=strategy, seed=seed))
+    pe = np.asarray(res.pe_of, np.int32)
+    assert hashlib.sha256(pe.tobytes()).hexdigest()[:16] == \
+        PARENT_MAPPINGS[(preset, strategy, seed)]
+
+
 def test_adaptive_beats_fixed_eps_on_balance():
     """GM (fixed eps) can exceed L_max where SharedMap cannot (paper §5/§6.4)."""
     g = G.gen_rgg(1200, seed=3)

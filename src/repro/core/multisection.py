@@ -124,7 +124,8 @@ from .graph import (Graph, assemble_padded, default_ell_deg,
                     padded_csr_indptr, repad_device, split_blocks, take_lanes)
 from .hierarchy import Hierarchy, adaptive_epsilon, adaptive_epsilon_jnp
 from .partition import (BatchedPartition, Preset, batched_partition,
-                        clear_batched_partition_cache, num_levels, partition)
+                        clear_batched_partition_cache, initial_width,
+                        num_levels, partition)
 from .refine import resolve_backend
 from ..kernels import ops as kops
 
@@ -487,9 +488,10 @@ class PlanGroup:
     ``eps`` may live on device (``eps_dev``) for the ``device`` strategy.
     ``eps``/``salts`` are per-member (position-derived, so independent of
     which batch the member eventually rides in). ``req`` is the id of the
-    request the group belongs to (trace spans only); ``level_sizes`` is set
+    request the group belongs to (trace spans only); ``counters`` is set
     by the dispatch: the dispatch's ``[B', levels]`` coarse vertex counts
-    on device and this group's first row in it.
+    and its compact-initial flag (``BatchedPartition.with_counters``), on
+    device, and this group's first row in the counts.
     """
 
     members: list
@@ -507,7 +509,7 @@ class PlanGroup:
     batch_orig: jax.Array | None = None  # [B, N] root ids (resident)
     eps_dev: jax.Array | None = None     # [B] f32 device eps (device strategy)
     req: int = 0
-    level_sizes: tuple[jax.Array, int] | None = None
+    counters: tuple[jax.Array, jax.Array, int] | None = None
 
     @property
     def exec_key(self) -> tuple:
@@ -614,12 +616,12 @@ def dispatch_group_batch(groups: list[PlanGroup], cache_stats: dict,
             eps = rep(eps)
             salts = rep(salts)
         if isinstance(fn, BatchedPartition):
-            parts, sizes = fn.with_level_sizes(batch, eps, salts)
+            parts, sizes, compact = fn.with_counters(batch, eps, salts)
         else:  # a stand-in callable gives the parts alone
             parts, sizes = fn(batch, eps, salts), None
     ofs = 0
     for gr in groups:
-        gr.level_sizes = None if sizes is None else (sizes, ofs)
+        gr.counters = None if sizes is None else (sizes, compact, ofs)
         ofs += len(gr.members)
     return parts, groups
 
@@ -694,7 +696,11 @@ class LevelPlanner:
     every graph the partition calls' v-cycles processed (each lane's graph,
     its coarse graphs, and the graph again for each finest-level v-cycle),
     and ``vcycle_padded_vertex_work``, the vertex slots those passes ran
-    at (each lane's padded N per pass).
+    at (each lane's padded N per pass, the initial partition's width for
+    the coarsest graph); ``initial_dispatches``, the dispatches whose
+    initial partition could run cut to a smaller width
+    (``partition.initial_width``), and ``initial_compact_dispatches``, those
+    of them where every lane's coarsest graph fitted it.
     """
 
     def __init__(self, g: Graph, h: Hierarchy, eps: float = 0.03,
@@ -724,7 +730,8 @@ class LevelPlanner:
                       "compile_cache": {"hits": 0, "misses": 0}}
         self.cache_stats = self.stats["compile_cache"]
         self.req = new_request_id() if req is None else int(req)
-        # per dispatched group: (level sizes, first row, lane sizes, N, levels)
+        # per dispatched group: (level sizes, compact flag, lane sizes,
+        # first row, N, Nc, levels)
         self._vcycle: list[tuple] = []
         self._groups: list[PlanGroup] | None = None
         self._done = False
@@ -906,16 +913,18 @@ class LevelPlanner:
         self._groups = None
 
     def _note_vcycle(self, gr: PlanGroup) -> None:
-        """Keep a dispatched group's v-cycle sizes, on device, for the
-        counter ``result()`` sums."""
-        if gr.level_sizes is None or gr.arity == 1:
-            return  # no sizes came back, or no v-cycle ran (k = 1)
-        sizes, first = gr.level_sizes
+        """Keep a dispatched group's v-cycle counters, on device, for the
+        counters ``result()`` sums."""
+        if gr.counters is None or gr.arity == 1:
+            return  # no counters came back, or no v-cycle ran (k = 1)
+        sizes, compact, first = gr.counters
         if gr.members[0].n < 0:  # device-strategy lanes: sizes on device
             ns = gr.batch.n
         else:
             ns = [m.n for m in gr.members]
-        self._vcycle.append((sizes, first, ns, gr.N, gr.levels))
+        Nc, _ = initial_width(gr.N, gr.M, gr.arity, gr.levels)
+        self._vcycle.append((sizes, compact, ns, first, gr.N, Nc,
+                             gr.levels))
 
     def _advance_resident(self, groups: list[PlanGroup], results: list) -> None:
         nxt: list[_LaneRef] = []
@@ -986,23 +995,31 @@ class LevelPlanner:
         return MultisectionResult(pe_of=self.pe_of, stats=self.stats)
 
     def _count_vcycle(self) -> None:
-        """Fetch every dispatch's level sizes at once, after the mapping is
-        complete, and sum them into the v-cycle counter."""
-        on_device = [(v[0], v[2]) for v in self._vcycle]
-        _acct(d2h_counter_bytes=sum(a.nbytes for pair in on_device
-                                    for a in pair if isinstance(a, jax.Array)),
+        """Fetch every dispatch's v-cycle counters at once, after the
+        mapping is complete, and sum them into the stats. The coarsest
+        graph's pass counts at the width its initial partition ran at."""
+        on_device = [v[:3] for v in self._vcycle]
+        _acct(d2h_counter_bytes=sum(a.nbytes for row in on_device
+                                    for a in row if isinstance(a, jax.Array)),
               d2h_counter_fetches=1)
         fetched = jax.device_get(on_device)
         passes = 1 + Preset.get(self.preset).vcycles
-        real = padded = 0
-        for (sizes, ns), (_, first, _, N, levels) in zip(fetched,
-                                                         self._vcycle):
+        real = padded = compact_n = dispatches = 0
+        for (sizes, compact, ns), (*_, first, N, Nc, levels) in zip(
+                fetched, self._vcycle):
             ns = np.asarray(ns, np.int64)
             lanes = np.asarray(sizes, np.int64)[first: first + ns.shape[0]]
             real += int(ns.sum()) * passes + int(lanes.sum())
             padded += ns.shape[0] * N * (passes + levels)
+            if Nc < N:
+                dispatches += 1
+                if compact:
+                    compact_n += 1
+                    padded -= ns.shape[0] * (N - Nc)
         self.stats["vcycle_real_vertex_work"] = real
         self.stats["vcycle_padded_vertex_work"] = padded
+        self.stats["initial_compact_dispatches"] = compact_n
+        self.stats["initial_dispatches"] = dispatches
         self._vcycle = []
 
 

@@ -8,7 +8,10 @@ repeated runs) and the best balanced partition wins.
 
 The whole pipeline is static-shape: one compiled program per
 (N, M, k, levels, preset), reused across all subgraphs of a hierarchy level
-and `vmap`-able for the LAYER/BUCKET scheduling strategies.
+and batched over them for the LAYER/BUCKET scheduling strategies. Every
+level runs at the fine graph's (N, M) except the initial partition, which
+runs on the coarsest graphs cut to a smaller static width wherever they
+all fit it (``initial_width``).
 """
 from __future__ import annotations
 
@@ -22,7 +25,8 @@ import jax
 import jax.numpy as jnp
 
 from .coarsen import coarsen_once
-from .graph import Graph, block_weights, default_ell_deg, edge_cut
+from .graph import (Graph, block_weights, default_ell_deg, edge_cut,
+                    repad_device)
 from .initial import initial_partition
 from .refine import lp_refine, rebalance
 
@@ -47,6 +51,11 @@ _PRESETS = {
 }
 
 
+def _coarsening_target(k: int, coarse_factor: int = 24) -> int:
+    """The vertex count the v-cycle coarsens toward for a ``k``-way cut."""
+    return max(coarse_factor * k, 64)
+
+
 def num_levels(n: int, k: int, coarse_factor: int = 24,
                max_degree: int | None = None) -> int:
     """Static coarsening depth: HEM shrinks ~1.6x/level; stop near 24*k.
@@ -60,7 +69,7 @@ def num_levels(n: int, k: int, coarse_factor: int = 24,
     shrink lands between 1x and 1.6x and the depth is EXTENDED (capped)
     so the coarsest graph still approaches the target size.
     """
-    target = max(coarse_factor * k, 64)
+    target = _coarsening_target(k, coarse_factor)
     if n <= target:
         return 0
     base = max(1, math.ceil(math.log(n / target) / math.log(1.6)))
@@ -75,130 +84,172 @@ def num_levels(n: int, k: int, coarse_factor: int = 24,
     return max(1, min(lv, 2 * base + 4))
 
 
-def _partition_single(
-    g: Graph, k: int, eps: jax.Array, levels: int, preset: Preset, salt: jax.Array,
-    backend: str = "auto", ell_deg: int | None = None, coarsen: str = "ell",
-) -> tuple[jax.Array, jax.Array]:
-    """One seeded multilevel run; all shapes stay (N, M). Returns the
-    partition and the ``[levels]`` i32 vertex counts of the coarse graphs,
-    level 1 first.
+def initial_width(N: int, M: int, k: int, levels: int) -> tuple[int, int]:
+    """Static ``(Nc, Mc)`` the initial partition of a coarsest graph runs
+    at when every lane's coarsest graph fits: four times the coarsening
+    target rounded up to a power of two, and four edge slots per vertex
+    slot. ``(N, M)`` where nothing is coarsened or nothing would shrink."""
+    Nc = 1 << (4 * _coarsening_target(k) - 1).bit_length()
+    if levels == 0 or Nc >= N:
+        return N, M
+    return Nc, min(M, 4 * Nc)
 
-    The stages run under ``jax.named_scope``s (``coarsen``, ``initial``,
-    ``refine``), so every op's ``op_name`` metadata, and with it a profiler
-    trace, says which stage it belongs to.
 
-    ``coarsen="ell"`` (default) is the fused v-cycle: coarsening runs
-    through the ELL kernels and both the downward (coarsen) and upward
-    (project + refine) level loops are ``lax.scan``s over stacked
-    same-shape graphs — ONE compiled loop body per (N, M, k, preset)
-    regardless of depth, instead of ``levels`` unrolled copies. That
-    removes the per-level retrace/compile cost that dominated the cold
-    path at 10^5+ vertices. ``coarsen="segment"`` keeps the seed's
-    unrolled segment-reduction path (the PR 8 baseline, and the bench
-    comparison mode).
-    """
-    total = g.total_weight()
-    Lmax = (1.0 + eps) * total / k
-
-    def initial(coarsest):
-        with jax.named_scope("initial"):
-            return initial_partition(
-                coarsest, k, Lmax, salt=salt,
-                polish_rounds=preset.coarsest_polish,
-                backend=backend, ell_deg=ell_deg)
-
+def _coarsen_down(g: Graph, levels: int, coarsen: str, deg: int):
+    """The down scan of one lane: its coarsest graph, what the up scan
+    reads (each level's fine graph and fine-to-coarse map) and the
+    ``[levels]`` i32 vertex counts of the coarse graphs, level 1 first.
+    It reads no restart salt, so the restarts share it."""
     if levels == 0:
-        sizes = jnp.zeros((0,), jnp.int32)
-        part = initial(g)
-    elif coarsen == "segment":
-        graphs = [g]
-        maps = []
-        cur = g
-        with jax.named_scope("coarsen"):
-            for lvl in range(levels):
-                cur, newid = coarsen_once(cur, salt=(lvl + 1) * 131 + 7)
-                graphs.append(cur)
-                maps.append(newid)
-            sizes = jnp.stack([c.n for c in graphs[1:]]).astype(jnp.int32)
-        part = initial(graphs[-1])
-        with jax.named_scope("refine"):
-            for lvl in range(levels - 1, -1, -1):
-                part = part[maps[lvl]]  # project to finer level
-                part = lp_refine(
-                    graphs[lvl], part, k, Lmax, rounds=preset.refine_rounds,
-                    salt=salt + 1000 + lvl, backend=backend, ell_deg=ell_deg,
-                )
-                part = rebalance(graphs[lvl], part, k, Lmax, rounds=4,
-                                 salt=salt + 2000 + lvl, backend=backend,
-                                 ell_deg=ell_deg)
-    else:
-        # static DEG cap for the coarsening kernels; reuse the refinement
-        # cap when the ELL refinement backend pinned one
-        deg_c = ell_deg if ell_deg is not None else default_ell_deg(g.N, g.M)
+        return g, None, jnp.zeros((0,), jnp.int32)
+    if coarsen == "segment":
+        graphs, maps = [g], []
+        for lvl in range(levels):
+            cur, newid = coarsen_once(graphs[-1], salt=(lvl + 1) * 131 + 7)
+            graphs.append(cur)
+            maps.append(newid)
+        sizes = jnp.stack([c.n for c in graphs[1:]]).astype(jnp.int32)
+        return graphs[-1], (graphs[:-1], maps), sizes
 
-        def down(cur, sl):
-            gc, newid = coarsen_once(cur, salt=sl, ell_deg=deg_c)
-            # emit the FINE graph of this level and the coarse size
-            return gc, (cur, newid, gc.n.astype(jnp.int32))
+    def down(cur, sl):
+        gc, newid = coarsen_once(cur, salt=sl, ell_deg=deg)
+        # emit the FINE graph of this level and the coarse size
+        return gc, (cur, newid, gc.n.astype(jnp.int32))
 
-        with jax.named_scope("coarsen"):
-            csalts = (jnp.arange(levels, dtype=jnp.int32) + 1) * 131 + 7
-            coarsest, (fines, maps, sizes) = jax.lax.scan(down, g, csalts)
-        part = initial(coarsest)
+    csalts = (jnp.arange(levels, dtype=jnp.int32) + 1) * 131 + 7
+    coarsest, (fines, maps, sizes) = jax.lax.scan(down, g, csalts)
+    return coarsest, (fines, maps), sizes
 
+
+def _initial_lanes(coarsest: Graph, Lmax: jax.Array, salts: jax.Array,
+                   k: int, preset: Preset, backend: str, deg: int,
+                   width: tuple[int, int]) -> jax.Array:
+    """Initial partition of each lane's coarsest graph for each restart
+    salt (``salts`` ``[B, R]``), cut to the static ``width``; the
+    ``[B, R, N]`` parts, 0 on the padding as at full width. Real vertices
+    and edges are a prefix of a coarse graph's arrays, so a lane whose
+    coarsest graph fits ``width`` gets the parts it gets at ``(N, M)``."""
+    N, M = coarsest.vwgt.shape[-1], coarsest.rows.shape[-1]
+    Nc, Mc = width
+
+    def one(c, L, s):
+        if (Nc, Mc) != (N, M):
+            c = repad_device(c, Nc, Mc)
+        p = initial_partition(c, k, L, salt=s,
+                              polish_rounds=preset.coarsest_polish,
+                              backend=backend, ell_deg=deg)
+        return jnp.pad(p, (0, N - Nc))
+
+    return jax.vmap(jax.vmap(one, in_axes=(None, None, 0)))(
+        coarsest, Lmax, salts)
+
+
+def _refine_up(g: Graph, trail, part: jax.Array, k: int, Lmax: jax.Array,
+               levels: int, preset: Preset, salt: jax.Array, backend: str,
+               ell_deg: int | None, coarsen: str) -> jax.Array:
+    """Project one restart's initial partition up through the levels with
+    LP refinement and rebalance, then the preset's finest-level v-cycles."""
+    def refine(gf, part, lvl):
+        part = lp_refine(gf, part, k, Lmax, rounds=preset.refine_rounds,
+                         salt=salt + 1000 + lvl, backend=backend,
+                         ell_deg=ell_deg)
+        return rebalance(gf, part, k, Lmax, rounds=4, salt=salt + 2000 + lvl,
+                         backend=backend, ell_deg=ell_deg)
+
+    if levels and coarsen == "segment":
+        graphs, maps = trail
+        for lvl in range(levels - 1, -1, -1):
+            part = refine(graphs[lvl], part[maps[lvl]], lvl)
+    elif levels:
         def up(part, x):
             gf, mp, lvl = x
-            part = part[mp]  # project to finer level
-            part = lp_refine(gf, part, k, Lmax, rounds=preset.refine_rounds,
-                             salt=salt + 1000 + lvl, backend=backend,
-                             ell_deg=ell_deg)
-            part = rebalance(gf, part, k, Lmax, rounds=4,
-                             salt=salt + 2000 + lvl, backend=backend,
-                             ell_deg=ell_deg)
-            return part, None
+            return refine(gf, part[mp], lvl), None  # project, then refine
 
-        with jax.named_scope("refine"):
-            lvls = jnp.arange(levels, dtype=jnp.int32)
-            part, _ = jax.lax.scan(up, part, (fines, maps, lvls),
-                                   reverse=True)
-
-    with jax.named_scope("refine"):
-        for cyc in range(preset.vcycles):
-            part = lp_refine(g, part, k, Lmax, rounds=preset.refine_rounds,
-                             salt=salt + 3000 + cyc, backend=backend,
-                             ell_deg=ell_deg)
-            part = rebalance(g, part, k, Lmax, rounds=4,
-                             salt=salt + 4000 + cyc, backend=backend,
-                             ell_deg=ell_deg)
-    return part, sizes
+        fines, maps = trail
+        lvls = jnp.arange(levels, dtype=jnp.int32)
+        part, _ = jax.lax.scan(up, part, (fines, maps, lvls), reverse=True)
+    for cyc in range(preset.vcycles):
+        part = lp_refine(g, part, k, Lmax, rounds=preset.refine_rounds,
+                         salt=salt + 3000 + cyc, backend=backend,
+                         ell_deg=ell_deg)
+        part = rebalance(g, part, k, Lmax, rounds=4, salt=salt + 4000 + cyc,
+                         backend=backend, ell_deg=ell_deg)
+    return part
 
 
-def _best_of_restarts(g: Graph, k: int, eps: jax.Array, levels: int,
-                      preset_name: str, salt, backend: str,
-                      ell_deg: int | None,
-                      coarsen: str) -> tuple[jax.Array, jax.Array]:
-    """:func:`partition`'s body: the best restart's partition and the
-    ``[levels]`` coarse vertex counts of its v-cycle."""
+def _partition_lanes(gs: Graph, eps: jax.Array, salts: jax.Array, k: int,
+                     levels: int, preset_name: str, backend: str,
+                     ell_deg: int | None, coarsen: str,
+                     width: tuple[int, int] | None = None):
+    """The body of :func:`partition` and :func:`batched_partition`: each
+    of the ``B`` lanes of the stacked ``gs`` partitioned with its ``eps``
+    and ``salt``, the best of the preset's restarts kept. Returns the
+    ``[B, N]`` parts, the ``[B, levels]`` i32 vertex counts of each lane's
+    coarse graphs (level 1 first) and an i32 scalar, 1 where the initial
+    partition ran cut to :func:`initial_width` (``width`` overrides it).
+
+    Three phases, each vmapped over the lanes (and over the restarts where
+    it reads the restart's salt), run under ``jax.named_scope``s, so each
+    op's ``op_name`` names its stage:
+
+    1. ``coarsen``: the down scan. ``coarsen="ell"`` (default) is the
+       fused v-cycle: ELL kernels, and both level loops ``lax.scan``s over
+       stacked same-shape graphs, ONE compiled loop body per (N, M, k,
+       preset) whatever the depth. ``"segment"`` keeps the seed's unrolled
+       segment-reduction path (the baseline of the fused one).
+    2. ``initial``: greedy growth and polish on the coarsest graphs. When
+       every lane's coarsest graph fits ``(Nc, Mc)`` it runs cut to that
+       width, else at ``(N, M)``: one ``lax.cond`` on a predicate over the
+       whole dispatch, formed outside the vmaps (a ``cond`` on a per-lane
+       predicate under ``vmap`` lowers to ``select`` and runs both).
+    3. ``refine`` (project, LP, rebalance per level; finest v-cycles) and
+       ``select`` (each restart's cut and overload, the best kept).
+    """
     preset = Preset.get(preset_name)
-    salt = jnp.asarray(salt, jnp.int32)
+    B, N = gs.vwgt.shape
+    M = gs.rows.shape[-1]
     if k == 1:
-        return jnp.zeros((g.N,), jnp.int32), jnp.zeros((levels,), jnp.int32)
+        return (jnp.zeros((B, N), jnp.int32),
+                jnp.zeros((B, levels), jnp.int32), jnp.int32(0))
+    rsalts = (salts[:, None] * 131
+              + jnp.arange(preset.restarts, dtype=jnp.int32) * 7919)
+    Lmax = jax.vmap(lambda g, e: (1.0 + e) * g.total_weight() / k)(gs, eps)
+    # static DEG cap for the ELL kernels: the refinement cap when the
+    # caller pinned one, else the fine graph's (also at a cut width)
+    deg = ell_deg if ell_deg is not None else default_ell_deg(N, M)
 
-    salts = salt * 131 + jnp.arange(preset.restarts, dtype=jnp.int32) * 7919
+    with jax.named_scope("coarsen"):
+        coarsest, trail, sizes = jax.vmap(
+            lambda g: _coarsen_down(g, levels, coarsen, deg))(gs)
 
-    def run(s):
-        p, sizes = _partition_single(g, k, eps, levels, preset, s, backend,
-                                     ell_deg, coarsen)
+    Nc, Mc = initial_width(N, M, k, levels) if width is None else width
+    initial = functools.partial(_initial_lanes, k=k, preset=preset,
+                                backend=backend, deg=deg)
+    with jax.named_scope("initial"):
+        if Nc < N:
+            fits = jnp.all((coarsest.n <= Nc) & (coarsest.m <= Mc))
+            part = jax.lax.cond(
+                fits, functools.partial(initial, width=(Nc, Mc)),
+                functools.partial(initial, width=(N, M)),
+                coarsest, Lmax, rsalts)
+            compact = fits.astype(jnp.int32)
+        else:
+            part = initial(coarsest, Lmax, rsalts, width=(N, M))
+            compact = jnp.int32(0)
+
+    def finish(g, trail, L, part, s):
+        with jax.named_scope("refine"):
+            part = _refine_up(g, trail, part, k, L, levels, preset, s,
+                              backend, ell_deg, coarsen)
         with jax.named_scope("select"):
-            cut = edge_cut(g, p)
-            Lmax = (1.0 + eps) * g.total_weight() / k
-            over = jnp.maximum(block_weights(g, p, k) - Lmax, 0.0).sum()
-            return p, sizes, cut + 1e6 * over
+            over = jnp.maximum(block_weights(g, part, k) - L, 0.0).sum()
+            return part, edge_cut(g, part) + 1e6 * over
 
-    parts, sizes, scores = jax.vmap(run)(salts)
+    restarts = jax.vmap(finish, in_axes=(None, None, None, 0, 0))
+    parts, scores = jax.vmap(restarts)(gs, trail, Lmax, part, rsalts)
     with jax.named_scope("select"):
-        best = jnp.argmin(scores)
-        return parts[best], sizes[best]
+        best = jax.vmap(lambda p, sc: p[jnp.argmin(sc)])(parts, scores)
+    return best, sizes, compact
 
 
 @functools.partial(
@@ -225,17 +276,22 @@ def partition(
     padding skews the in-jit default by up to 2x; see core/refine.py).
     ``coarsen`` selects the coarsening implementation: ``"ell"`` (default)
     is the fused kernel v-cycle, ``"segment"`` the seed's unrolled
-    segment-reduction path (see ``_partition_single``).
+    segment-reduction path (see ``_partition_lanes``, which this runs
+    with a lane axis of one).
     """
-    return _best_of_restarts(g, k, eps, levels, preset_name, salt, backend,
-                             ell_deg, coarsen)[0]
+    one = lambda a: jnp.reshape(a, (1,) + jnp.shape(a))
+    return _partition_lanes(
+        jax.tree_util.tree_map(one, g), one(eps),
+        one(jnp.asarray(salt, jnp.int32)), k, levels, preset_name, backend,
+        ell_deg, coarsen)[0][0]
 
 
 class BatchedPartition:
     """A jitted vmapped partition: calling it gives the ``[B, N]`` parts;
-    :meth:`with_level_sizes` also gives the ``[B, levels]`` i32 vertex
-    counts of each lane's coarse graphs (the winning restart's), from the
-    same program and without a transfer."""
+    :meth:`with_counters` also gives the ``[B, levels]`` i32 vertex counts
+    of each lane's coarse graphs and the dispatch's i32 0/1 flag of a
+    compact initial partition (``_partition_lanes``), from the same program
+    and without a transfer."""
 
     def __init__(self, fn: Callable):
         self._fn = fn
@@ -243,7 +299,7 @@ class BatchedPartition:
     def __call__(self, gs: Graph, eps: jax.Array, salts: jax.Array):
         return self._fn(gs, eps, salts)[0]
 
-    def with_level_sizes(self, gs: Graph, eps: jax.Array, salts: jax.Array):
+    def with_counters(self, gs: Graph, eps: jax.Array, salts: jax.Array):
         return self._fn(gs, eps, salts)
 
 
@@ -276,10 +332,8 @@ def batched_partition(k: int, levels: int, preset: str, backend: str,
     with _BATCHED_LOCK:
         fn = _BATCHED_CACHE.get(key)
         if fn is None:
-            fn = BatchedPartition(jax.jit(lambda gs, ee, ss: jax.vmap(
-                lambda g1, e1, s1: _best_of_restarts(
-                    g1, k, e1, levels, preset, s1, backend, ell_deg, coarsen)
-            )(gs, ee, ss)))
+            fn = BatchedPartition(jax.jit(lambda gs, ee, ss: _partition_lanes(
+                gs, ee, ss, k, levels, preset, backend, ell_deg, coarsen)))
             _BATCHED_CACHE[key] = fn
     return fn
 

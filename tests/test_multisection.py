@@ -406,3 +406,40 @@ def test_merged_dispatch_lane_independent(g):
     merged = execute_group_batch([g1s[0], g2s[0]], cs, pad_batch_pow2=True)
     assert np.array_equal(merged[0], solo1)
     assert np.array_equal(merged[1], solo2)
+
+
+def test_initial_compact_counter_counts_the_dispatches_that_can_shrink(
+        g, monkeypatch):
+    """``initial_dispatches`` counts the dispatches whose initial partition
+    can run cut to ``initial_width``; on a small rgg every one of them
+    does."""
+    from repro.core import multisection as MS
+    from repro.core.partition import initial_width
+    can_shrink = []
+    dispatch = MS.dispatch_group_batch
+
+    def spy(groups, *a, **kw):
+        gr = groups[0]
+        can_shrink.append(gr.arity > 1 and initial_width(
+            gr.N, gr.M, gr.arity, gr.levels)[0] < gr.N)
+        return dispatch(groups, *a, **kw)
+
+    monkeypatch.setattr(MS, "dispatch_group_batch", spy)
+    res = shared_map(g, H_PAPER, SharedMapConfig(eps=0.03, preset="fast"))
+    assert 0 < sum(can_shrink) < len(can_shrink)
+    assert res.stats["initial_dispatches"] == sum(can_shrink)
+    assert res.stats["initial_compact_dispatches"] == sum(can_shrink)
+
+
+def test_vcycle_counter_counts_the_coarsest_pass_at_its_width(g):
+    from repro.core.partition import Preset, initial_width, num_levels
+    res = hierarchical_multisection(g, Hierarchy(a=(3,), d=(1.0,)),
+                                    preset="eco")
+    n, m = int(g.n), int(g.m)
+    N0, M0 = 1 << (n - 1).bit_length(), 1 << (m - 1).bit_length()
+    levels = num_levels(N0, 3)
+    Nc, _ = initial_width(N0, M0, 3, levels)
+    assert Nc < N0 and res.stats["initial_compact_dispatches"] == 1
+    passes = 1 + Preset.get("eco").vcycles
+    assert res.stats["vcycle_padded_vertex_work"] == \
+        N0 * (passes + levels) - (N0 - Nc)

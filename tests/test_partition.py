@@ -1,5 +1,8 @@
 """Multilevel partitioner: balance, quality sanity, determinism."""
+import functools
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 from hypcompat import given, settings, st
@@ -289,3 +292,117 @@ def test_admit_threshold_respects_capacity():
     # unconstrained block takes every candidate targeting it
     b3 = np.asarray(cand) & (np.asarray(best) == 3)
     assert np.array_equal(acc[b3], np.full(b3.sum(), True))
+
+
+# --- the initial partition cut to a static width -----------------------------
+
+def _lanes(*gs):
+    """A stacked ``[B, ...]`` Graph of same-shape lanes."""
+    return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *gs)
+
+
+def _parity_graph(gen):
+    return G.gen_rgg(2000, seed=5) if gen == "rgg" else G.gen_grid(45)
+
+
+@pytest.mark.parametrize("k", [3, 4, 8])
+@pytest.mark.parametrize("gen", ["rgg", "grid"])
+@pytest.mark.parametrize("backend", ["xla", "ell"])
+def test_initial_phase_cut_to_its_width_is_bitwise_the_full_width(
+        backend, gen, k):
+    """The coarsest graph's initial partition at ``initial_width`` equals
+    the one at the fine graph's (N, M) bit for bit, 0 on the padding."""
+    from repro.core.partition import (Preset, _coarsen_down, _initial_lanes,
+                                      initial_width)
+    g = _parity_graph(gen)
+    N, M = g.N, g.M
+    levels = num_levels(N, k)
+    Nc, Mc = initial_width(N, M, k, levels)
+    assert Nc < N
+    deg = G.default_ell_deg(N, M)
+    coarsest, _, _ = _coarsen_down(g, levels, "ell", deg)
+    n = int(coarsest.n)
+    assert n <= Nc and int(coarsest.m) <= Mc
+    Lmax = jnp.asarray([1.03 * float(g.total_weight()) / k], jnp.float32)
+    salts = jnp.asarray([[7, 7926]], jnp.int32)
+
+    def run(width):
+        fn = functools.partial(_initial_lanes, k=k, preset=Preset.get("eco"),
+                               backend=backend, deg=deg, width=width)
+        return np.asarray(jax.jit(fn)(_lanes(coarsest), Lmax, salts))
+
+    full, cut = run((N, M)), run((Nc, Mc))
+    assert cut.shape == full.shape == (1, 2, N)
+    np.testing.assert_array_equal(cut, full)
+    assert (cut[..., n:] == 0).all() and cut[..., :n].max() == k - 1
+
+
+@pytest.mark.parametrize("coarsen", ["ell", "segment"])
+def test_partition_and_batched_equal_the_full_width_branch(coarsen):
+    """``partition`` and ``batched_partition`` take the compact branch and
+    give the parts the full-width branch gives."""
+    from repro.core.partition import (_partition_lanes, batched_partition,
+                                      partition)
+    g = G.gen_rgg(2000, seed=5)
+    k, eps, salt = 3, jnp.float32(0.03), jnp.asarray([5], jnp.int32)
+    levels = num_levels(g.N, k)
+    full = jax.jit(lambda gs, e, s: _partition_lanes(
+        gs, e, s, k, levels, "fast", "xla", None, coarsen,
+        width=(g.N, g.M)))
+    ref, _, took = full(_lanes(g), eps[None], salt)
+    assert int(took) == 0
+    np.testing.assert_array_equal(
+        np.asarray(partition(g, k, eps, levels, "fast", 5, "xla", None,
+                             coarsen)), np.asarray(ref[0]))
+    fn = batched_partition(k, levels, "fast", "xla", None, coarsen)
+    parts, _, took = fn.with_counters(_lanes(g), eps[None], salt)
+    assert int(took) == 1
+    np.testing.assert_array_equal(np.asarray(parts), np.asarray(ref))
+
+
+def _no_fit_graph(shape, n):
+    """An edgeless or star graph: HEM cannot shrink it below ``n - levels``."""
+    if shape == "edgeless":
+        return G.from_edges(n, np.zeros(0, np.int64), np.zeros(0, np.int64))
+    return G.from_edges(n, np.zeros(n - 1, np.int64), np.arange(1, n))
+
+
+@pytest.mark.parametrize("shape", ["edgeless", "star"])
+def test_initial_full_width_where_the_coarsest_graph_does_not_fit(shape):
+    from repro.core.hierarchy import Hierarchy
+    from repro.core.multisection import hierarchical_multisection
+    from repro.core.partition import initial_width
+    n, k, eps = 8192, 4, 0.03
+    g = _no_fit_graph(shape, n)
+    levels = num_levels(g.N, k)
+    assert levels > 0 and initial_width(g.N, g.M, k, levels)[0] < g.N
+    res = hierarchical_multisection(g, Hierarchy(a=(k,), d=(1.0,)),
+                                    eps=eps, preset="fast")
+    assert res.stats["initial_dispatches"] == 1
+    assert res.stats["initial_compact_dispatches"] == 0
+    # every pass, the coarsest graph's too, counted at the full width
+    assert res.stats["vcycle_padded_vertex_work"] == g.N * (1 + levels)
+    w = np.bincount(res.pe_of, minlength=k)
+    assert w.sum() == n and w.max() <= (1 + eps) * n / k
+
+
+def test_one_lane_that_does_not_fit_sends_the_dispatch_to_full_width():
+    """The fit test is over the whole dispatch: one lane whose coarsest
+    graph overflows sends every lane to the full width, and each lane's
+    parts equal a run of that lane alone."""
+    from repro.core.partition import batched_partition
+    fits = G.gen_rgg(2000, seed=5)
+    wide = G.from_edges(2000, np.zeros(0, np.int64), np.zeros(0, np.int64),
+                        N=fits.N, M=fits.M)
+    k = 3
+    fn = batched_partition(k, num_levels(fits.N, k), "fast", "xla", None)
+    eps = jnp.full((2,), 0.03, jnp.float32)
+    salts = jnp.asarray([5, 6], jnp.int32)
+    parts, _, took = fn.with_counters(_lanes(fits, wide), eps, salts)
+    assert int(took) == 0
+    for i, lane in enumerate((fits, wide)):
+        alone, _, took = fn.with_counters(_lanes(lane), eps[i:i + 1],
+                                          salts[i:i + 1])
+        assert int(took) == (i == 0)
+        np.testing.assert_array_equal(np.asarray(parts[i]),
+                                      np.asarray(alone[0]))

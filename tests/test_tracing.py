@@ -35,7 +35,7 @@ def test_batched_partition_ops_carry_their_stage(g):
     """Every stage of the fused v-cycle names its ops in the compiled
     program's metadata, through the vmaps of lanes and restarts."""
     fn = batched_partition(4, num_levels(g.N, 4), "eco", "auto", None)
-    names = _op_names(jax.jit(fn.with_level_sizes).lower(
+    names = _op_names(jax.jit(fn.with_counters).lower(
         _stack(g, 2), jnp.full((2,), 0.03, jnp.float32),
         jnp.arange(2, dtype=jnp.int32)).compile())
     for stage in ("coarsen", "initial", "refine", "select"):
@@ -67,7 +67,7 @@ def test_level_sizes_equal_the_coarsening_cascade(g):
     assert levels >= 2
     deg = G.default_ell_deg(g.N, g.M)
     fn = batched_partition(4, levels, "fast", "auto", deg)
-    parts, sizes = fn.with_level_sizes(
+    parts, sizes, _ = fn.with_counters(
         _stack(g, 2), jnp.full((2,), 0.03, jnp.float32),
         jnp.asarray([3, 5], jnp.int32))
     ns, _ = coarsen_cascade(g, levels, ell_deg=deg)

@@ -7,13 +7,13 @@ proposals are contracted. This is the standard shared-memory parallel HEM
 (cf. Mt-Metis / Mt-KaHyPar coarsening) re-expressed over static-shape
 arrays so it vmaps across subgraphs.
 
-Two implementations share this module:
+Three implementations share this module:
 
 * the **segment path** (:func:`hem_match` / :func:`contract`) — the seed's
   edge-array formulation: ``segment_max``/``segment_min`` proposal passes
-  and a sort-based contraction. Exact (no degree cap); kept as the
-  reference for the contraction invariants and as the PR 8 comparison
-  mode (``partition(..., coarsen="segment")``).
+  and a sort-based contraction. Exact (no degree cap); the comparison
+  mode ``partition(..., coarsen="segment")``; its :func:`contract` is
+  also the skewed path's.
 * the **ELL kernel path** (:func:`hem_match_ell` / :func:`contract_ell` /
   :func:`coarsen_once` with ``ell_deg``) — row-tiled scans over the padded
   ``[N, DEG]`` ELL adjacency, dispatched through ``kernels/ops``
@@ -25,6 +25,13 @@ Two implementations share this module:
   (the refinement kernels' overflow policy); coarsening is purely
   heuristic — partitions stay valid, cut/balance are always evaluated on
   the untruncated fine graph. Backends agree bitwise (kernels/ref.py).
+* the **skewed path** (:func:`coarsen_skewed_once`), for graphs whose
+  degree tail the DEG cap cuts (a power-law graph's hubs): HEM over the
+  ELL adjacency as above, then :func:`pair_twohop` pairs what HEM left
+  unmatched (vertices with identical neighbourhoods, and isolated
+  vertices), then the exact :func:`contract`, so no edge is lost. On a
+  Graph500 graph HEM alone stalls: a hub matches one leaf a level and
+  isolated vertices never match.
 """
 from __future__ import annotations
 
@@ -123,12 +130,12 @@ def contract(g: Graph, labels: jax.Array) -> tuple[Graph, jax.Array]:
     cu = newid[g.rows]
     cv = newid[g.cols]
     valid = emask & (cu != cv)
-    # sort edges by (cu, cv) with invalid parked at cu = N (dropped on scatter)
-    cu_s_key = jnp.where(valid, cu, N)
-    order1 = jnp.argsort(jnp.where(valid, cv, N), stable=True)
-    cu1, cv1, w1 = cu_s_key[order1], cv[order1], jnp.where(valid, g.ewgt, 0.0)[order1]
-    order2 = jnp.argsort(cu1, stable=True)
-    cu2, cv2, w2 = cu1[order2], cv1[order2], w1[order2]
+    # one stable sort of the edges by (cu, cv), invalid parked at cu = N
+    # (dropped on scatter); duplicates keep their order, so their weights
+    # sum in a fixed order
+    cu2, cv2, w2 = jax.lax.sort(
+        (jnp.where(valid, cu, N), jnp.where(valid, cv, N),
+         jnp.where(valid, g.ewgt, 0.0)), num_keys=2, is_stable=True)
 
     valid_s = cu2 < N
     head = valid_s & (
@@ -183,6 +190,13 @@ def hem_match_ell(g: Graph, adj: jax.Array, adw: jax.Array,
     ``kernels/ops.hem_propose`` scan; rows past the DEG cap see only
     their first DEG neighbours.
     """
+    return _hem_rounds_ell(g, adj, adw, rounds, salt, use_pallas)[0]
+
+
+def _hem_rounds_ell(g: Graph, adj: jax.Array, adw: jax.Array, rounds: int,
+                    salt, use_pallas: bool | None):
+    """The rounds of :func:`hem_match_ell`: labels [N] and the [N] i32
+    0/1 matched flags (1 on padding)."""
     N = g.N
     vmask = vertex_mask(g)
     idx = jnp.arange(N, dtype=jnp.int32)
@@ -202,8 +216,7 @@ def hem_match_ell(g: Graph, adj: jax.Array, adw: jax.Array,
         matched = matched | new_match.astype(jnp.int32)
         return labels, matched
 
-    labels, matched = jax.lax.fori_loop(0, rounds, one_round, (labels, matched))
-    return labels
+    return jax.lax.fori_loop(0, rounds, one_round, (labels, matched))
 
 
 def contract_ell(g: Graph, labels: jax.Array, adj: jax.Array, adw: jax.Array,
@@ -282,6 +295,78 @@ def contract_ell(g: Graph, labels: jax.Array, adj: jax.Array, adw: jax.Array,
         m=m_coarse.astype(jnp.int32),
     )
     return gc, newid
+
+
+# ---------------------------------------------------------------------------
+# skewed path (heavy degree tails: HEM, two-hop pairing, exact contraction)
+# ---------------------------------------------------------------------------
+
+def neighbourhood_hash(adj: jax.Array) -> jax.Array:
+    """[N] i32 hash of each ELL row's set of neighbour ids: a wrapping sum
+    of a mix of each id, so the slot order does not matter. Rows with the
+    same neighbours hash alike; others collide with odds of about 2^-32."""
+    v = (adj.astype(jnp.uint32) + jnp.uint32(0x9E3779B9)) * _HASH_A
+    v = (v ^ (v >> 15)) * jnp.uint32(0x2C1B3C6D)
+    v = v ^ (v >> 13)
+    h = jnp.sum(jnp.where(adj < adj.shape[0], v, jnp.uint32(0)), axis=1,
+                dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(h, jnp.int32)
+
+
+def pair_twohop(g: Graph, labels: jax.Array, matched: jax.Array,
+                adj: jax.Array, salt, max_vwgt) -> tuple[jax.Array, jax.Array]:
+    """Pair the vertices HEM left unmatched: those with the same
+    neighbours (METIS's two-hop matching of vertices with identical
+    adjacency: a hub's leaves, say), and the isolated ones with each other
+    (KaMinPar's isolated-vertex clustering). Rows past the ELL cap, whose
+    neighbours the row does not hold in full, take no part.
+
+    One stable sort by (class, neighbourhood hash, weight, salted jitter),
+    the class 0 for the first kind, 1 for isolated vertices and 2 for the
+    rest; then the entries at even ranks of each (class, hash) group pair
+    with the next one, where the pair weighs at most ``max_vwgt``. Within
+    a group the lightest pair first, so once a pair is over the cap every
+    later one is too. Returns the labels (pairs share the smaller id, as
+    HEM's do) and the i32 number of pairs made."""
+    N, DEG = adj.shape
+    idx = jnp.arange(N, dtype=jnp.int32)
+    deg = g.indptr[1:] - g.indptr[:-1]
+    free = vertex_mask(g) & (matched == 0)
+    cls = jnp.where(free & (deg > 0) & (deg <= DEG), 0,
+                    jnp.where(free & (deg == 0), 1, 2)).astype(jnp.int32)
+    sig = jnp.where(cls == 0, neighbourhood_hash(adj), 0)
+    jit_ = _edge_jitter(idx, idx, salt * 7 + 29)
+    cls, sig, w, _, v = jax.lax.sort((cls, sig, g.vwgt, jit_, idx),
+                                     num_keys=4, is_stable=True)
+    pos = jnp.arange(N, dtype=jnp.int32)
+    same = (cls[1:] == cls[:-1]) & (sig[1:] == sig[:-1])
+    head = jnp.concatenate([jnp.ones((1,), bool), ~same])
+    start = jax.lax.cummax(jnp.where(head, pos, 0))
+    even = (((pos - start) % 2) == 0)[:-1]
+    pair = even & same & (cls[:-1] < 2) & (w[:-1] + w[1:] <= max_vwgt)
+    leader = jnp.minimum(v[:-1], v[1:])
+    labels = (labels.at[jnp.where(pair, v[:-1], N)].set(leader, mode="drop")
+              .at[jnp.where(pair, v[1:], N)].set(leader, mode="drop"))
+    return labels, jnp.sum(pair.astype(jnp.int32))
+
+
+def coarsen_skewed_once(g: Graph, salt=0, rounds: int = 3,
+                        ell_deg: int = 8, max_vwgt=jnp.inf,
+                        use_pallas: bool | None = None):
+    """One level for a graph with a heavy degree tail: HEM over the ELL
+    adjacency as :func:`coarsen_once`, then :func:`pair_twohop` (under the
+    ``twohop`` scope), then the exact :func:`contract`, which keeps every
+    edge, the rows past the DEG cap included: the coarse edge weight plus
+    the weight contracted inside coarse vertices is the fine total.
+    Returns (coarse graph at the same padded shapes, fine->coarse map
+    [N], i32 pairs made by the two-hop and isolated pairing)."""
+    adj, adw, _ = ell_adjacency(g, ell_deg)
+    labels, matched = _hem_rounds_ell(g, adj, adw, rounds, salt, use_pallas)
+    with jax.named_scope("twohop"):
+        labels, pairs = pair_twohop(g, labels, matched, adj, salt,
+                                    max_vwgt)
+    gc, newid = contract(g, labels)
+    return gc, newid, pairs
 
 
 def coarsen_once(g: Graph, salt=0, rounds: int = 3,

@@ -62,17 +62,26 @@ in-flight hierarchies (serve/mapper.MappingService):
   planner, so the direct path and the mapping service share every
   planning decision — the precondition for bit-identical results.
 
+Skewed requests
+---------------
+A request whose root has more than an eighth of its edge slots past the
+root's ELL degree cap (a power-law graph: :func:`_coarsen_mode`, read with
+the root's n and m in one fetch) coarsens every dispatch by the skewed down
+scan (``coarsen="skewed"`` in ``core/partition.py``). The choice is part
+of :attr:`PlanGroup.exec_key` and of the program key, so such a request
+runs programs of its own and is never stacked with a mesh's lanes.
+
 Compile-cache policy
 --------------------
 Single-subgraph calls go straight to the jitted ``partition``; batched
 calls go through :func:`partition.batched_partition`, a process-wide memo
 of jitted vmapped wrappers keyed by ``(k, levels, preset, backend,
-ell_deg)``. The device-resident split/repack/eps/scatter programs live in
+ell_deg, coarsen)``. The device-resident split/repack/eps/scatter programs live in
 their own memo (:func:`_jit_op`), keyed by static shapes (+ the kernel
 backend for programs that dispatch through kernels/ops). Both are shared
 across hierarchy levels, strategies and calls. :func:`_note_program`
 tracks every distinct XLA partition-program key ``(N, M, batch, k,
-levels, preset, backend, ell_deg)``: first sighting in the process =
+levels, preset, backend, ell_deg, coarsen)``: first sighting in the process =
 compile (miss), later sightings = reuse (hit); per-run counts land in
 ``stats["compile_cache"]``.
 
@@ -82,7 +91,8 @@ Module-level counters (:func:`transfer_stats` / :func:`reset_transfer_stats`)
 record every host<->device array movement the multisection performs:
 bulk graph uploads (`_stack_to_device`, `_partition_one`), bulk label /
 mirror fetches (``d2h_array_fetches``), per-level metadata fetches
-(``d2h_meta_fetches``) and the one fetch of the v-cycle counter after the
+(``d2h_meta_fetches``; the root's n, m and degree figure are one) and
+the one fetch of the v-cycle counter after the
 mapping is complete (``d2h_counter_fetches``). On the ``device`` strategy
 a request costs exactly ONE array fetch — the final ``pe_of`` — which the
 ``device_pipeline`` benchmark and tests assert. (On CPU hosts the
@@ -120,12 +130,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .graph import (Graph, assemble_padded, default_ell_deg,
+from .graph import (ELL_DEG_CAP, Graph, assemble_padded, default_ell_deg,
                     padded_csr_indptr, repad_device, split_blocks, take_lanes)
 from .hierarchy import Hierarchy, adaptive_epsilon, adaptive_epsilon_jnp
-from .partition import (BatchedPartition, Preset, batched_partition,
-                        clear_batched_partition_cache, initial_width,
-                        num_levels, partition)
+from .partition import (BatchedPartition, Preset, _coarsening_target,
+                        batched_partition, clear_batched_partition_cache,
+                        initial_width, num_levels, partition)
 from .refine import resolve_backend
 from ..kernels import ops as kops
 
@@ -284,6 +294,12 @@ def _split(hg: _HostGraph, part: np.ndarray, k: int, child_depth: int,
     return children
 
 
+def _host_coarsen_mode(hg: _HostGraph) -> str:
+    """:func:`_coarsen_mode` of a host graph."""
+    deg = np.bincount(hg.rows, minlength=hg.n)
+    return _coarsen_mode(hg.n, hg.m, _slots_past_caps(deg))
+
+
 # ---------------------------------------------------------------------------
 # the compiled-callable caches
 # ---------------------------------------------------------------------------
@@ -325,11 +341,34 @@ def _ell_deg_for(members, backend: str) -> int | None:
     return default_ell_deg(1, mean)  # N=1, M=mean -> cap from the real mean
 
 
+# every ELL cap default_ell_deg can give: multiples of 8 up to the hard cap
+_ELL_CAPS = np.arange(8, ELL_DEG_CAP + 1, 8, dtype=np.int32)
+
+
+def _slots_past_caps(deg) -> np.ndarray:
+    """[len(_ELL_CAPS)] i32: for each cap, the edge slots of the rows'
+    degrees ``deg`` that lie past it (numpy or traced)."""
+    xp = np if isinstance(deg, np.ndarray) else jnp
+    over = xp.maximum(deg[:, None] - xp.asarray(_ELL_CAPS)[None, :], 0)
+    return xp.sum(over, axis=0).astype(xp.int32)
+
+
+def _coarsen_mode(n: int, m: int, past) -> str:
+    """``"skewed"`` where more than an eighth of the root's ``m`` directed
+    edge slots lie past its ELL cap (the cap ``_ell_deg_for`` gives the
+    root dispatch), else ``"ell"``. Every dispatch of the request then
+    coarsens that way. A mesh keeps under 1.1% of its slots past the cap
+    (rgg 100 to 16,384 vertices); a Graph500 graph has 28-53% there."""
+    cap = default_ell_deg(1, (m + max(n, 1) - 1) // max(n, 1))
+    return "skewed" if 8 * int(past[cap // 8 - 1]) > m else "ell"
+
+
 def _note_program(N: int, M: int, batch: int, k: int, levels: int, preset: str,
-                  backend: str, ell_deg: int | None, cache_stats: dict) -> None:
+                  backend: str, ell_deg: int | None, cache_stats: dict,
+                  coarsen: str = "ell") -> None:
     """Track XLA program reuse: the first sighting of a program key in the
     process is a compile (miss), every later one a cache hit."""
-    key = (N, M, batch, k, levels, preset, backend, ell_deg,
+    key = (N, M, batch, k, levels, preset, backend, ell_deg, coarsen,
            kops.kernel_backend())
     with _EXEC_LOCK:
         hit = key in _SEEN_SHAPES
@@ -405,6 +444,16 @@ def _root_op(Ns: int, Ms: int, N0: int, M0: int) -> Callable:
         batch = jax.tree_util.tree_map(lambda a: a[None], g2)
         return batch, orig[None], jnp.sum(g2.vwgt)
     return _level_op(("root", Ns, Ms, N0, M0), run)
+
+
+def _root_meta_op(Ns: int, Ms: int) -> Callable:
+    """g -> i32 [2 + len(_ELL_CAPS)]: the root's n, m and the edge slots
+    past each ELL cap (:func:`_slots_past_caps`), read in one fetch."""
+    def run(g: Graph):
+        past = _slots_past_caps(g.indptr[1:] - g.indptr[:-1])
+        return jnp.concatenate([jnp.stack([g.n, g.m]).astype(jnp.int32),
+                                past])
+    return _level_op(("root_meta", Ns, Ms), run)
 
 
 def _zeros_op(n: int) -> Callable:
@@ -489,9 +538,12 @@ class PlanGroup:
     ``eps``/``salts`` are per-member (position-derived, so independent of
     which batch the member eventually rides in). ``req`` is the id of the
     request the group belongs to (trace spans only); ``counters`` is set
-    by the dispatch: the dispatch's ``[B', levels]`` coarse vertex counts
-    and its compact-initial flag (``BatchedPartition.with_counters``), on
-    device, and this group's first row in the counts.
+    by the dispatch: the dispatch's ``[B', levels]`` coarse vertex counts,
+    its compact-initial flag and its two-hop pairs and levels run (None
+    unless ``coarsen="skewed"``; ``BatchedPartition.with_counters``), on
+    device, and this group's first row in the counts. ``coarsen`` names
+    the down scan, the same for every group of a request
+    (:func:`_coarsen_mode`).
     """
 
     members: list
@@ -509,14 +561,15 @@ class PlanGroup:
     batch_orig: jax.Array | None = None  # [B, N] root ids (resident)
     eps_dev: jax.Array | None = None     # [B] f32 device eps (device strategy)
     req: int = 0
-    counters: tuple[jax.Array, jax.Array, int] | None = None
+    counters: tuple | None = None
+    coarsen: str = "ell"  # the down scan (partition.py): "ell" or "skewed"
 
     @property
     def exec_key(self) -> tuple:
         """Groups with equal keys run the same compiled executable and may
         be stacked into ONE dispatch (the cross-request coalescing key)."""
         return (self.N, self.M, self.arity, self.levels, self.preset,
-                self.backend, self.deg)
+                self.backend, self.deg, self.coarsen)
 
     def eps_array(self) -> jax.Array:
         if self.eps_dev is not None:
@@ -534,13 +587,14 @@ class PlanGroup:
 
 def plan_level(work: list, h: Hierarchy, eps: float, preset: str,
                seed: int, total_weight: float, adaptive: bool, backend: str,
-               bucketed: bool = True) -> list[PlanGroup]:
+               bucketed: bool = True, coarsen: str = "ell") -> list[PlanGroup]:
     """Group one level's pending subgraphs into dispatch units.
 
     ``bucketed=True`` is the BUCKET strategy (power-of-two shape buckets);
     ``False`` is LAYER (one group per arity, padded to the level max).
     Members may be `_HostGraph`s or `_LaneRef`s — planning only reads the
-    ``n/m/depth/uid/wsum`` metadata either exposes.
+    ``n/m/depth/uid/wsum`` metadata either exposes. ``coarsen`` is the
+    request's down scan (:func:`_coarsen_mode`).
     """
     groups: dict[tuple[int, int, int], list] = {}
     for hg in work:
@@ -562,6 +616,7 @@ def plan_level(work: list, h: Hierarchy, eps: float, preset: str,
             deg=_ell_deg_for(members, backend),
             eps=[_eps_for(m, h, eps, total_weight, adaptive) for m in members],
             salts=[seed * 100003 + m.uid for m in members],
+            coarsen=coarsen,
         ))
     return out
 
@@ -595,9 +650,9 @@ def dispatch_group_batch(groups: list[PlanGroup], cache_stats: dict,
             "repro.dispatch", req=",".join(map(str, reqs)),
             depth=g0.members[0].depth, lanes=Bp):
         _note_program(g0.N, g0.M, Bp, g0.arity, g0.levels, g0.preset,
-                      g0.backend, g0.deg, cache_stats)
+                      g0.backend, g0.deg, cache_stats, g0.coarsen)
         fn = batched_partition(g0.arity, g0.levels, g0.preset, g0.backend,
-                               g0.deg)
+                               g0.deg, g0.coarsen)
 
         batches = [gr.graph_batch() for gr in groups]
         eps_parts = [gr.eps_array() for gr in groups]
@@ -616,12 +671,12 @@ def dispatch_group_batch(groups: list[PlanGroup], cache_stats: dict,
             eps = rep(eps)
             salts = rep(salts)
         if isinstance(fn, BatchedPartition):
-            parts, sizes, compact = fn.with_counters(batch, eps, salts)
+            parts, *counters = fn.with_counters(batch, eps, salts)
         else:  # a stand-in callable gives the parts alone
-            parts, sizes = fn(batch, eps, salts), None
+            parts, counters = fn(batch, eps, salts), None
     ofs = 0
     for gr in groups:
-        gr.counters = None if sizes is None else (sizes, compact, ofs)
+        gr.counters = None if counters is None else (*counters, ofs)
         ofs += len(gr.members)
     return parts, groups
 
@@ -697,10 +752,20 @@ class LevelPlanner:
     its coarse graphs, and the graph again for each finest-level v-cycle),
     and ``vcycle_padded_vertex_work``, the vertex slots those passes ran
     at (each lane's padded N per pass, the initial partition's width for
-    the coarsest graph); ``initial_dispatches``, the dispatches whose
+    the coarsest graph; a skewed dispatch's levels count where they ran);
+    ``initial_dispatches``, the dispatches whose
     initial partition could run cut to a smaller width
     (``partition.initial_width``), and ``initial_compact_dispatches``, those
-    of them where every lane's coarsest graph fitted it.
+    of them where every lane's coarsest graph fitted it;
+    ``coarsen_merges``, the pairs every lane's down scan made (its vertices
+    less its coarsest graph's), and ``coarsen_twohop_merges``, those of
+    them the two-hop and isolated pairing made (``coarsen="skewed"``);
+    ``coarsest_real_vertices`` and ``coarsening_target``, the sums over the
+    lanes of the coarsest graph's vertices and of the target the depth was
+    set for (``max(24 k, 64)``). All come in the one counter fetch.
+
+    ``coarsen`` is the request's down scan, decided from the root's
+    degrees as it is read (:func:`_coarsen_mode`).
     """
 
     def __init__(self, g: Graph, h: Hierarchy, eps: float = 0.03,
@@ -753,13 +818,15 @@ class LevelPlanner:
         self.total_weight = root.wsum
         self._tw_f32 = jnp.float32(np.float32(root.vwgt.sum()))
         self._root_deg = _ell_deg_for([root], self.backend)
+        self.coarsen = _host_coarsen_mode(root)
         self.pe_of = np.zeros(root.n, np.int32)
         self._current: list = [root]
 
     def _init_resident(self, g: Graph) -> None:
-        n_root = int(g.n)
-        m_root = int(g.m)
-        _acct(d2h_meta_bytes=8, d2h_meta_fetches=1)
+        meta = np.asarray(_root_meta_op(g.N, g.M)(g))
+        _acct(d2h_meta_bytes=meta.nbytes, d2h_meta_fetches=1)
+        n_root, m_root = int(meta[0]), int(meta[1])
+        self.coarsen = _coarsen_mode(n_root, m_root, meta[2:])
         self.n_root = n_root
         self.N0 = _next_pow2(n_root)
         self.M0 = _next_pow2(max(m_root, 1))
@@ -821,7 +888,7 @@ class LevelPlanner:
                     groups = plan_level(
                         self._work, self.h, self.eps, self.preset, self.seed,
                         self.total_weight, self.adaptive, self.backend,
-                        self.bucketed)
+                        self.bucketed, self.coarsen)
                     if self.resident:
                         for gr in groups:
                             gr.resident = True
@@ -842,7 +909,8 @@ class LevelPlanner:
             members=list(work), N=self.N0, M=self.M0, arity=arity,
             levels=num_levels(self.N0, arity), preset=self.preset,
             backend=self.backend, deg=self._root_deg,
-            eps=[], salts=[self.seed * 100003 + w.uid for w in work])
+            eps=[], salts=[self.seed * 100003 + w.uid for w in work],
+            coarsen=self.coarsen)
         if self.resident:
             lvl = work[0].level
             gr.resident = True
@@ -917,14 +985,14 @@ class LevelPlanner:
         counters ``result()`` sums."""
         if gr.counters is None or gr.arity == 1:
             return  # no counters came back, or no v-cycle ran (k = 1)
-        sizes, compact, first = gr.counters
+        sizes, compact, skew, first = gr.counters
         if gr.members[0].n < 0:  # device-strategy lanes: sizes on device
             ns = gr.batch.n
         else:
             ns = [m.n for m in gr.members]
         Nc, _ = initial_width(gr.N, gr.M, gr.arity, gr.levels)
-        self._vcycle.append((sizes, compact, ns, first, gr.N, Nc,
-                             gr.levels))
+        self._vcycle.append((sizes, compact, ns, skew, first, gr.N, Nc,
+                             gr.levels, _coarsening_target(gr.arity)))
 
     def _advance_resident(self, groups: list[PlanGroup], results: list) -> None:
         nxt: list[_LaneRef] = []
@@ -998,28 +1066,42 @@ class LevelPlanner:
         """Fetch every dispatch's v-cycle counters at once, after the
         mapping is complete, and sum them into the stats. The coarsest
         graph's pass counts at the width its initial partition ran at."""
-        on_device = [v[:3] for v in self._vcycle]
+        on_device = [v[:4] for v in self._vcycle]
         _acct(d2h_counter_bytes=sum(a.nbytes for row in on_device
                                     for a in row if isinstance(a, jax.Array)),
               d2h_counter_fetches=1)
         fetched = jax.device_get(on_device)
         passes = 1 + Preset.get(self.preset).vcycles
         real = padded = compact_n = dispatches = 0
-        for (sizes, compact, ns), (*_, first, N, Nc, levels) in zip(
-                fetched, self._vcycle):
+        merges = twohop = coarsest = target = 0
+        for (sizes, compact, ns, skew), (*_, first, N, Nc, levels, tgt) in \
+                zip(fetched, self._vcycle):
             ns = np.asarray(ns, np.int64)
-            lanes = np.asarray(sizes, np.int64)[first: first + ns.shape[0]]
-            real += int(ns.sum()) * passes + int(lanes.sum())
-            padded += ns.shape[0] * N * (passes + levels)
+            rows = slice(first, first + ns.shape[0])
+            lanes = np.asarray(sizes, np.int64)[rows]
+            ran = np.ones(levels, bool)
+            if skew is not None:  # a skewed dispatch: its levels that ran
+                twohop += int(np.asarray(skew[0], np.int64)[rows].sum())
+                ran = np.asarray(skew[1]) > 0
+            real += int(ns.sum()) * passes + int(lanes[:, ran].sum())
+            padded += ns.shape[0] * N * (passes + int(ran.sum()))
             if Nc < N:
                 dispatches += 1
                 if compact:
                     compact_n += 1
                     padded -= ns.shape[0] * (N - Nc)
+            last = lanes[:, -1] if levels else ns
+            merges += int((ns - last).sum())
+            coarsest += int(last.sum())
+            target += ns.shape[0] * tgt
         self.stats["vcycle_real_vertex_work"] = real
         self.stats["vcycle_padded_vertex_work"] = padded
         self.stats["initial_compact_dispatches"] = compact_n
         self.stats["initial_dispatches"] = dispatches
+        self.stats["coarsen_merges"] = merges
+        self.stats["coarsen_twohop_merges"] = twohop
+        self.stats["coarsest_real_vertices"] = coarsest
+        self.stats["coarsening_target"] = target
         self._vcycle = []
 
 
@@ -1047,16 +1129,16 @@ def _eps_for(hg, h: Hierarchy, eps: float, total_weight: float,
 
 def _partition_one(hg: _HostGraph, k: int, eps_val: float, preset: str,
                    salt: int, backend: str, cache_stats: dict,
-                   pad_n: int | None = None, pad_m: int | None = None) -> np.ndarray:
-    N = pad_n or _next_pow2(hg.n)
-    M = pad_m or _next_pow2(max(hg.m, 1))
+                   coarsen: str = "ell") -> np.ndarray:
+    N = _next_pow2(hg.n)
+    M = _next_pow2(max(hg.m, 1))
     lv = num_levels(N, k)
     deg = _ell_deg_for([hg], backend)
-    _note_program(N, M, 0, k, lv, preset, backend, deg, cache_stats)
+    _note_program(N, M, 0, k, lv, preset, backend, deg, cache_stats, coarsen)
     g = hg.to_device(N, M)
     _acct(h2d_bytes=4 * (N + 3 * M + N + 1 + 2), h2d_transfers=7)
     part = np.asarray(partition(g, k, jnp.float32(eps_val), lv, preset,
-                                jnp.int32(salt), backend, deg))
+                                jnp.int32(salt), backend, deg, coarsen))
     _acct(d2h_bytes=part.nbytes, d2h_array_fetches=1)
     return part[: hg.n]
 
@@ -1112,6 +1194,7 @@ def hierarchical_multisection(
     root = host_graph_from(g)
     root.depth = h.l
     total_weight = root.wsum
+    coarsen = _host_coarsen_mode(root)
     pe_of = np.zeros(root.n, np.int32)
     stats = {"partition_calls": 0, "levels": [], "strategy": strategy,
              "padded_vertex_work": 0, "real_vertex_work": 0,
@@ -1127,7 +1210,7 @@ def hierarchical_multisection(
             stats["real_vertex_work"] += int(realn)
 
     ctx = (h, eps, preset, seed, total_weight, adaptive, backend, record,
-           cache_stats, checkpoint)
+           cache_stats, checkpoint, coarsen)
     current = [root]
     while current:
         if checkpoint is not None:
@@ -1158,7 +1241,7 @@ def _children_of(hg: _HostGraph, part: np.ndarray, h: Hierarchy) -> list[_HostGr
 
 def _run_naive(work, ctx):
     (h, eps, preset, seed, total_weight, adaptive, backend, record,
-     cache_stats, checkpoint) = ctx
+     cache_stats, checkpoint, coarsen) = ctx
     out = []
     for hg in work:
         if checkpoint is not None:
@@ -1166,7 +1249,7 @@ def _run_naive(work, ctx):
         arity = h.a[hg.depth - 1]
         e = _eps_for(hg, h, eps, total_weight, adaptive)
         part = _partition_one(hg, arity, e, preset, seed * 100003 + hg.uid,
-                              backend, cache_stats)
+                              backend, cache_stats, coarsen)
         record(_next_pow2(hg.n), hg.n)
         out.extend(_children_of(hg, part, h))
     return out
@@ -1192,7 +1275,7 @@ def _run_queue(work, ctx, workers: int | None = None):
         import os
         workers = max(2, min(4, os.cpu_count() or 2))
     (h, eps, preset, seed, total_weight, adaptive, backend, record,
-     cache_stats, checkpoint) = ctx
+     cache_stats, checkpoint, coarsen) = ctx
     cv = threading.Condition()
     heap: list[tuple[int, int, _HostGraph]] = []
     out: list[_HostGraph] = []
@@ -1217,7 +1300,8 @@ def _run_queue(work, ctx, workers: int | None = None):
                 arity = h.a[task.depth - 1]
                 e = _eps_for(task, h, eps, total_weight, adaptive)
                 part = _partition_one(task, arity, e, preset,
-                                      seed * 100003 + task.uid, backend, cache_stats)
+                                      seed * 100003 + task.uid, backend,
+                                      cache_stats, coarsen)
                 record(_next_pow2(task.n), task.n)
                 children = _children_of(task, part, h)
             except BaseException as exc:  # propagate to the caller

@@ -24,7 +24,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from .coarsen import coarsen_once
+from .coarsen import coarsen_once, coarsen_skewed_once
 from .graph import (Graph, block_weights, default_ell_deg, edge_cut,
                     repad_device)
 from .initial import initial_partition
@@ -84,15 +84,30 @@ def num_levels(n: int, k: int, coarse_factor: int = 24,
     return max(1, min(lv, 2 * base + 4))
 
 
-def initial_width(N: int, M: int, k: int, levels: int) -> tuple[int, int]:
+def initial_width(N: int, M: int, k: int, levels: int,
+                  skewed: bool = False) -> tuple[int, int]:
     """Static ``(Nc, Mc)`` the initial partition of a coarsest graph runs
     at when every lane's coarsest graph fits: four times the coarsening
     target rounded up to a power of two, and four edge slots per vertex
-    slot. ``(N, M)`` where nothing is coarsened or nothing would shrink."""
+    slot, sixteen for a ``skewed`` dispatch (``coarsen="skewed"``), whose
+    coarsest graphs are dense. ``(N, M)`` where nothing is coarsened or
+    nothing would shrink."""
     Nc = 1 << (4 * _coarsening_target(k) - 1).bit_length()
     if levels == 0 or Nc >= N:
         return N, M
-    return Nc, min(M, 4 * Nc)
+    return Nc, min(M, (16 if skewed else 4) * Nc)
+
+
+def load_limit(g: Graph, eps, k: int) -> jax.Array:
+    """The heaviest block a ``k``-way partition of ``g`` may have:
+    ``(1 + eps) W / k``, but not below ``ceil(W / k)`` where every vertex
+    weight is whole, since no partition of whole weights does better. A
+    lane whose adaptive eps leaves less than one unit above ``W / k``
+    would otherwise hold a block one unit over with no move to cure it."""
+    W = g.total_weight()
+    limit = (1.0 + eps) * W / k
+    whole = jnp.all(g.vwgt == jnp.round(g.vwgt))
+    return jnp.where(whole, jnp.maximum(limit, jnp.ceil(W / k)), limit)
 
 
 def _coarsen_down(g: Graph, levels: int, coarsen: str, deg: int):
@@ -121,6 +136,54 @@ def _coarsen_down(g: Graph, levels: int, coarsen: str, deg: int):
     return coarsest, (fines, maps), sizes
 
 
+# a skewed dispatch coarsens while a level sheds at least 1/_STALL of some
+# lane's vertices; the levels after the first that does not are identities
+_STALL = 32
+
+
+def _coarsen_skewed_lanes(gs: Graph, levels: int, deg: int, k: int):
+    """The down scan of a skewed dispatch (``coarsen="skewed"``), over its
+    ``B`` lanes at once: each level runs :func:`coarsen_skewed_once` on
+    every lane while the level before shed at least ``1/_STALL`` of some
+    lane's vertices. After the first level that did not, one ``lax.cond``
+    on that dispatch-wide flag, outside the lane ``vmap``, makes every
+    later level an identity that costs nothing, and the up scan skips it
+    (:func:`_refine_up_lanes`). The two-hop and isolated pairs weigh at
+    most 1.5 times an average vertex of the coarsening target (METIS's
+    maxvwgt); HEM has no cap. Returns the coarsest lanes, the up scan's
+    level-major trail (fine graphs, fine-to-coarse maps, an i32 1/0 per
+    level where it ran), the ``[B, levels]`` i32 coarse vertex counts and
+    the ``([B, levels] pairs of the two-hop and isolated pairing,
+    [levels] ran)`` counters."""
+    B, N = gs.vwgt.shape
+    idx = jnp.arange(N, dtype=jnp.int32)
+    max_vwgt = 1.5 * jnp.sum(gs.vwgt, axis=1) / _coarsening_target(k)
+    once = jax.vmap(lambda g, mv, sl: coarsen_skewed_once(
+        g, salt=sl, ell_deg=deg, max_vwgt=mv), in_axes=(0, 0, None))
+
+    def hold(cur):
+        newid = jnp.where(idx[None, :] < cur.n[:, None], idx[None, :], N - 1)
+        return cur, newid, jnp.zeros((B,), jnp.int32)
+
+    def run(cur, sl):
+        # ops inside a cond's branch lose the scopes around the cond
+        with jax.named_scope("coarsen"):
+            return once(cur, max_vwgt, sl)
+
+    def down(carry, sl):
+        cur, live = carry
+        gc, newid, pairs = jax.lax.cond(
+            live, lambda c: run(c, sl), hold, cur)
+        shed = jnp.any((cur.n - gc.n) * _STALL >= jnp.maximum(cur.n, 1))
+        return (gc, live & shed), (cur, newid, live.astype(jnp.int32),
+                                   gc.n.astype(jnp.int32), pairs)
+
+    csalts = (jnp.arange(levels, dtype=jnp.int32) + 1) * 131 + 7
+    (coarsest, _), (fines, maps, ran, sizes, pairs) = jax.lax.scan(
+        down, (gs, jnp.bool_(True)), csalts)
+    return coarsest, (fines, maps, ran), sizes.T, (pairs.T, ran)
+
+
 def _initial_lanes(coarsest: Graph, Lmax: jax.Array, salts: jax.Array,
                    k: int, preset: Preset, backend: str, deg: int,
                    width: tuple[int, int]) -> jax.Array:
@@ -144,17 +207,58 @@ def _initial_lanes(coarsest: Graph, Lmax: jax.Array, salts: jax.Array,
         coarsest, Lmax, salts)
 
 
+def _refine_level(gf: Graph, part: jax.Array, Lmax: jax.Array, lvl,
+                  salt: jax.Array, k: int, preset: Preset, backend: str,
+                  ell_deg: int | None) -> jax.Array:
+    """LP refinement and rebalance of one restart's partition of one
+    level's fine graph."""
+    part = lp_refine(gf, part, k, Lmax, rounds=preset.refine_rounds,
+                     salt=salt + 1000 + lvl, backend=backend,
+                     ell_deg=ell_deg)
+    return rebalance(gf, part, k, Lmax, rounds=4, salt=salt + 2000 + lvl,
+                     backend=backend, ell_deg=ell_deg)
+
+
+def _refine_up_lanes(trail, part: jax.Array, Lmax: jax.Array, levels: int,
+                     rsalts: jax.Array, k: int, preset: Preset, backend: str,
+                     ell_deg: int | None) -> jax.Array:
+    """The up scan of a skewed dispatch over its lanes and restarts at once
+    (``part`` ``[B, R, N]``): project, then refine as :func:`_refine_up`
+    does, each level the down scan ran; one ``lax.cond`` per level,
+    outside the vmaps, skips the identities
+    (:func:`_coarsen_skewed_lanes`)."""
+    lanes = jax.vmap(jax.vmap(
+        functools.partial(_refine_level, k=k, preset=preset, backend=backend,
+                          ell_deg=ell_deg),
+        in_axes=(None, 0, None, None, 0)), in_axes=(0, 0, 0, None, 0))
+
+    def refine(gf, part, Lmax, lvl, rsalts):
+        # ops inside a cond's branch lose the scopes around the cond
+        with jax.named_scope("refine"):
+            return lanes(gf, part, Lmax, lvl, rsalts)
+
+    def up(part, x):
+        gf, mp, lvl, ran = x
+        part = jax.vmap(lambda p, m: p[:, m])(part, mp)
+        part = jax.lax.cond(ran > 0,
+                            lambda p: refine(gf, p, Lmax, lvl, rsalts),
+                            lambda p: p, part)
+        return part, None
+
+    fines, maps, ran = trail
+    lvls = jnp.arange(levels, dtype=jnp.int32)
+    part, _ = jax.lax.scan(up, part, (fines, maps, lvls, ran), reverse=True)
+    return part
+
+
 def _refine_up(g: Graph, trail, part: jax.Array, k: int, Lmax: jax.Array,
                levels: int, preset: Preset, salt: jax.Array, backend: str,
                ell_deg: int | None, coarsen: str) -> jax.Array:
     """Project one restart's initial partition up through the levels with
     LP refinement and rebalance, then the preset's finest-level v-cycles."""
     def refine(gf, part, lvl):
-        part = lp_refine(gf, part, k, Lmax, rounds=preset.refine_rounds,
-                         salt=salt + 1000 + lvl, backend=backend,
-                         ell_deg=ell_deg)
-        return rebalance(gf, part, k, Lmax, rounds=4, salt=salt + 2000 + lvl,
-                         backend=backend, ell_deg=ell_deg)
+        return _refine_level(gf, part, Lmax, lvl, salt, k, preset, backend,
+                             ell_deg)
 
     if levels and coarsen == "segment":
         graphs, maps = trail
@@ -185,8 +289,12 @@ def _partition_lanes(gs: Graph, eps: jax.Array, salts: jax.Array, k: int,
     of the ``B`` lanes of the stacked ``gs`` partitioned with its ``eps``
     and ``salt``, the best of the preset's restarts kept. Returns the
     ``[B, N]`` parts, the ``[B, levels]`` i32 vertex counts of each lane's
-    coarse graphs (level 1 first) and an i32 scalar, 1 where the initial
-    partition ran cut to :func:`initial_width` (``width`` overrides it).
+    coarse graphs (level 1 first), an i32 scalar, 1 where the initial
+    partition ran cut to :func:`initial_width` (``width`` overrides it),
+    and for ``coarsen="skewed"`` the ``([B, levels] i32 pairs of each
+    level's two-hop and isolated pairing, [levels] i32 1/0 where the level
+    ran)`` counters (None otherwise, so the other modes' programs are as
+    they were).
 
     Three phases, each vmapped over the lanes (and over the restarts where
     it reads the restart's salt), run under ``jax.named_scope``s, so each
@@ -195,8 +303,13 @@ def _partition_lanes(gs: Graph, eps: jax.Array, salts: jax.Array, k: int,
     1. ``coarsen``: the down scan. ``coarsen="ell"`` (default) is the
        fused v-cycle: ELL kernels, and both level loops ``lax.scan``s over
        stacked same-shape graphs, ONE compiled loop body per (N, M, k,
-       preset) whatever the depth. ``"segment"`` keeps the seed's unrolled
-       segment-reduction path (the baseline of the fused one).
+       preset) whatever the depth. ``"skewed"`` scans
+       :func:`coarsen.coarsen_skewed_once` (HEM, two-hop and isolated
+       pairing, exact contraction) over all lanes at once, for graphs
+       whose degree tail the ELL cap cuts, and stops at the first level
+       that barely shrinks (:func:`_coarsen_skewed_lanes`); its up scan
+       skips the levels it did not run. ``"segment"`` keeps the seed's
+       unrolled segment-reduction path (the baseline of the fused one).
     2. ``initial``: greedy growth and polish on the coarsest graphs. When
        every lane's coarsest graph fits ``(Nc, Mc)`` it runs cut to that
        width, else at ``(N, M)``: one ``lax.cond`` on a predicate over the
@@ -208,21 +321,29 @@ def _partition_lanes(gs: Graph, eps: jax.Array, salts: jax.Array, k: int,
     preset = Preset.get(preset_name)
     B, N = gs.vwgt.shape
     M = gs.rows.shape[-1]
+    skewed = coarsen == "skewed" and levels > 0
     if k == 1:
-        return (jnp.zeros((B, N), jnp.int32),
-                jnp.zeros((B, levels), jnp.int32), jnp.int32(0))
+        none = jnp.zeros((B, levels), jnp.int32)
+        return (jnp.zeros((B, N), jnp.int32), none, jnp.int32(0),
+                (none, jnp.zeros((levels,), jnp.int32)) if skewed else None)
     rsalts = (salts[:, None] * 131
               + jnp.arange(preset.restarts, dtype=jnp.int32) * 7919)
-    Lmax = jax.vmap(lambda g, e: (1.0 + e) * g.total_weight() / k)(gs, eps)
+    Lmax = jax.vmap(lambda g, e: load_limit(g, e, k))(gs, eps)
     # static DEG cap for the ELL kernels: the refinement cap when the
     # caller pinned one, else the fine graph's (also at a cut width)
     deg = ell_deg if ell_deg is not None else default_ell_deg(N, M)
 
     with jax.named_scope("coarsen"):
-        coarsest, trail, sizes = jax.vmap(
-            lambda g: _coarsen_down(g, levels, coarsen, deg))(gs)
+        if skewed:
+            coarsest, trail, sizes, skew = _coarsen_skewed_lanes(
+                gs, levels, deg, k)
+        else:
+            coarsest, trail, sizes = jax.vmap(
+                lambda g: _coarsen_down(g, levels, coarsen, deg))(gs)
+            skew = None
 
-    Nc, Mc = initial_width(N, M, k, levels) if width is None else width
+    Nc, Mc = (initial_width(N, M, k, levels, skewed)
+              if width is None else width)
     initial = functools.partial(_initial_lanes, k=k, preset=preset,
                                 backend=backend, deg=deg)
     with jax.named_scope("initial"):
@@ -237,9 +358,16 @@ def _partition_lanes(gs: Graph, eps: jax.Array, salts: jax.Array, k: int,
             part = initial(coarsest, Lmax, rsalts, width=(N, M))
             compact = jnp.int32(0)
 
+    up_levels = levels
+    if skewed:
+        with jax.named_scope("refine"):
+            part = _refine_up_lanes(trail, part, Lmax, levels, rsalts, k,
+                                    preset, backend, ell_deg)
+        trail, up_levels = None, 0  # the finest v-cycles are left
+
     def finish(g, trail, L, part, s):
         with jax.named_scope("refine"):
-            part = _refine_up(g, trail, part, k, L, levels, preset, s,
+            part = _refine_up(g, trail, part, k, L, up_levels, preset, s,
                               backend, ell_deg, coarsen)
         with jax.named_scope("select"):
             over = jnp.maximum(block_weights(g, part, k) - L, 0.0).sum()
@@ -249,7 +377,7 @@ def _partition_lanes(gs: Graph, eps: jax.Array, salts: jax.Array, k: int,
     parts, scores = jax.vmap(restarts)(gs, trail, Lmax, part, rsalts)
     with jax.named_scope("select"):
         best = jax.vmap(lambda p, sc: p[jnp.argmin(sc)])(parts, scores)
-    return best, sizes, compact
+    return best, sizes, compact, skew
 
 
 @functools.partial(
@@ -275,9 +403,10 @@ def partition(
     refinement; pass one computed from the REAL vertex/edge counts (pow2
     padding skews the in-jit default by up to 2x; see core/refine.py).
     ``coarsen`` selects the coarsening implementation: ``"ell"`` (default)
-    is the fused kernel v-cycle, ``"segment"`` the seed's unrolled
-    segment-reduction path (see ``_partition_lanes``, which this runs
-    with a lane axis of one).
+    is the fused kernel v-cycle, ``"skewed"`` the same with two-hop and
+    isolated pairing and exact contraction, ``"segment"`` the seed's
+    unrolled segment-reduction path (see ``_partition_lanes``, which this
+    runs with a lane axis of one).
     """
     one = lambda a: jnp.reshape(a, (1,) + jnp.shape(a))
     return _partition_lanes(
@@ -289,9 +418,10 @@ def partition(
 class BatchedPartition:
     """A jitted vmapped partition: calling it gives the ``[B, N]`` parts;
     :meth:`with_counters` also gives the ``[B, levels]`` i32 vertex counts
-    of each lane's coarse graphs and the dispatch's i32 0/1 flag of a
-    compact initial partition (``_partition_lanes``), from the same program
-    and without a transfer."""
+    of each lane's coarse graphs, the dispatch's i32 0/1 flag of a
+    compact initial partition and, for ``coarsen="skewed"``, the
+    ``[B, levels]`` i32 two-hop pairs (``_partition_lanes``), from the same
+    program and without a transfer."""
 
     def __init__(self, fn: Callable):
         self._fn = fn

@@ -283,3 +283,135 @@ def test_coarsen_cascade_telemetry_shapes():
     ns, ms = np.asarray(ns), np.asarray(ms)
     assert ns.shape == ms.shape == (4,)
     assert np.all(np.diff(ns) <= 0) and ns[-1] >= 1   # monotone shrink
+
+
+# --- the skewed path: two-hop and isolated pairing, exact contraction -------
+
+def _skewed_graph(kind: str, seed: int = 0):
+    """Small graphs whose rows run past an ELL cap of 8: a Kronecker graph,
+    a star with isolated vertices, hubs whose leaves share them, and a
+    path among isolated vertices. Duplicate edges merged; padded past the
+    real counts."""
+    from repro.core.taskgraph import TaskGraph
+    rng = np.random.default_rng(seed)
+    if kind == "kron":
+        g0 = G.gen_kron(7, seed=seed)
+        n = int(g0.n)
+        u = np.asarray(g0.rows)[: int(g0.m)]
+        v = np.asarray(g0.cols)[: int(g0.m)]
+    elif kind == "star":
+        n = 90
+        u = np.zeros(60, np.int64)
+        v = np.arange(1, 61)                          # 29 isolated
+    elif kind == "hubs":
+        n = 150
+        hub = rng.integers(0, 4, 120)                 # leaves of 4 hubs
+        u = np.concatenate([hub, hub[:30] + 1, rng.integers(4, 124, 20)])
+        v = np.concatenate([np.arange(4, 124), np.arange(4, 34),
+                            rng.integers(4, 124, 20)])
+    else:                                             # "isolated"
+        n = 100
+        u, v = np.arange(0, 29), np.arange(1, 30)     # 70 isolated
+    tg = TaskGraph.from_edges(n, u, v)
+    N = 1 << int(n).bit_length()
+    return tg.to_graph(N=N, M=max(8, 4 << int(max(tg.m, 1)).bit_length()))
+
+
+def _quotient(g, newid):
+    """numpy: the graph of g's vertex classes under ``newid``, edges
+    merged and summed, listed by (row, col)."""
+    n, m = int(g.n), int(g.m)
+    nid = np.asarray(newid)[:n]
+    r = nid[np.asarray(g.rows)[:m]]
+    c = nid[np.asarray(g.cols)[:m]]
+    w = np.asarray(g.ewgt, np.float64)[:m]
+    keep = r != c
+    key, inv = np.unique(r[keep] * g.N + c[keep], return_inverse=True)
+    wsum = np.zeros(key.size)
+    np.add.at(wsum, inv, w[keep])
+    vw = np.bincount(nid, weights=np.asarray(g.vwgt, np.float64)[:n])
+    return key // g.N, key % g.N, wsum, vw, float(w[~keep].sum())
+
+
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+@pytest.mark.parametrize("kind", ["kron", "star", "hubs", "isolated"])
+def test_skewed_coarsen_is_the_quotient_graph(monkeypatch, kind, backend):
+    """Three levels of the skewed path: each coarse graph is the numpy
+    quotient of its fine graph under the labels, rows past the cap kept
+    whole, and the coarse edge weight plus the weight contracted inside
+    coarse vertices is the fine total on every level."""
+    from repro.core.coarsen import coarsen_skewed_once
+    _flip_backend(monkeypatch, backend)
+    g = _skewed_graph(kind)
+    deg = 8
+    ind = np.asarray(g.indptr)
+    assert kind == "isolated" or np.diff(ind).max() > deg
+    total = float(np.asarray(g.ewgt, np.float64).sum())
+    for level in range(3):
+        gc, newid, pairs = coarsen_skewed_once(g, salt=level, ell_deg=deg,
+                                               max_vwgt=8.0)
+        n, nc, mc = int(g.n), int(gc.n), int(gc.m)
+        nid = np.asarray(newid)[:n]
+        sizes = np.bincount(nid, minlength=nc)
+        assert sizes.min() >= 1 and sizes.max() <= 2       # pairs only
+        rows, cols, w, vw, inside = _quotient(g, newid)
+        assert mc == rows.size and nc == vw.size
+        assert np.array_equal(np.asarray(gc.rows)[:mc], rows)
+        assert np.array_equal(np.asarray(gc.cols)[:mc], cols)
+        np.testing.assert_array_equal(np.asarray(gc.ewgt)[:mc], w)
+        np.testing.assert_array_equal(np.asarray(gc.vwgt)[:nc], vw)
+        assert np.array_equal(np.asarray(gc.indptr),
+                              G.padded_csr_indptr(np.asarray(gc.rows), mc,
+                                                  g.N))
+        assert float(np.asarray(gc.ewgt, np.float64).sum()) + inside == total
+        total -= inside
+        assert int(pairs) <= n - nc
+        g = gc
+    if kind in ("star", "isolated"):
+        assert int(g.n) < _skewed_graph(kind).n // 2  # leaves, isolated pair
+
+
+def _twohop_reference(g, labels, matched, adj, salt, max_vwgt):
+    """numpy: pairs of unmatched vertices with the same set of neighbours
+    (rows within the cap), or both isolated; each group in order of
+    (weight, the program's salted jitter, id), its 1st with its 2nd, 3rd
+    with 4th, ..., each pair at most ``max_vwgt``."""
+    from repro.core.coarsen import _edge_jitter
+    N, DEG = adj.shape
+    n = int(g.n)
+    deg = np.diff(np.asarray(g.indptr))[:n]
+    adj = np.asarray(adj)
+    w = np.asarray(g.vwgt)
+    idx = jnp.arange(N, dtype=jnp.int32)
+    jit_ = np.asarray(_edge_jitter(idx, idx, salt * 7 + 29))
+    labels = np.asarray(labels).copy()
+    groups: dict = {}
+    for v in range(n):
+        if np.asarray(matched)[v] or deg[v] > DEG:
+            continue
+        key = frozenset(adj[v][adj[v] < N].tolist()) if deg[v] else "iso"
+        groups.setdefault(key, []).append(v)
+    made = 0
+    for members in groups.values():
+        members.sort(key=lambda v: (w[v], jit_[v], v))
+        for a, b in zip(members[0::2], members[1::2]):
+            if w[a] + w[b] <= max_vwgt:
+                labels[a] = labels[b] = min(a, b)
+                made += 1
+    return labels, made
+
+
+@pytest.mark.parametrize("backend", ["xla", "interpret"])
+@pytest.mark.parametrize("kind,seed", [("hubs", 0), ("hubs", 1),
+                                       ("kron", 2), ("star", 3)])
+def test_twohop_pairs_equal_the_reference(monkeypatch, kind, seed, backend):
+    from repro.core.coarsen import _hem_rounds_ell, pair_twohop
+    _flip_backend(monkeypatch, backend)
+    g = _skewed_graph(kind, seed)
+    adj, adw, _ = ell_adjacency(g, 8)
+    labels, matched = _hem_rounds_ell(g, adj, adw, 3, seed, None)
+    got, made = pair_twohop(g, labels, matched, adj, seed, 3.0)
+    want, want_made = _twohop_reference(g, labels, matched, adj, seed, 3.0)
+    assert want_made > 0
+    assert int(made) == want_made
+    assert np.array_equal(np.asarray(got), want)

@@ -210,6 +210,15 @@ def test_bucket_equals_naive_bitwise(g):
     execute_group_batch) must reproduce the naive strategy's mapping
     bit-for-bit. A planning/batching bug shows up here even though both
     in-process bucket paths share the planner code."""
+    _assert_bucket_equals_naive(g)
+
+
+def test_bucket_equals_naive_bitwise_on_a_power_law_graph(fresh_programs):
+    """The same oracle where every dispatch takes the skewed down scan."""
+    _assert_bucket_equals_naive(G.gen_kron(9, seed=4))
+
+
+def _assert_bucket_equals_naive(g):
     a = hierarchical_multisection(g, H_PAPER, eps=0.03, preset="fast",
                                   strategy="bucket", seed=2)
     b = hierarchical_multisection(g, H_PAPER, eps=0.03, preset="fast",
@@ -432,14 +441,132 @@ def test_initial_compact_counter_counts_the_dispatches_that_can_shrink(
 
 
 def test_vcycle_counter_counts_the_coarsest_pass_at_its_width(g):
-    from repro.core.partition import Preset, initial_width, num_levels
+    _assert_vcycle_counter(g, "rgg")
+
+
+def test_vcycle_counter_counts_a_skewed_dispatch(fresh_programs):
+    """A power-law root takes the skewed down scan: the counter counts
+    the levels it ran, its two-hop pairs and its coarsest graph."""
+    _assert_vcycle_counter(G.gen_kron(11, seed=5), "kron")
+
+
+def _assert_vcycle_counter(g, kind):
+    import jax
+    from repro.core.graph import default_ell_deg
+    from repro.core.partition import (Preset, _coarsen_down,
+                                      _coarsen_skewed_lanes, initial_width,
+                                      num_levels)
     res = hierarchical_multisection(g, Hierarchy(a=(3,), d=(1.0,)),
                                     preset="eco")
     n, m = int(g.n), int(g.m)
     N0, M0 = 1 << (n - 1).bit_length(), 1 << (m - 1).bit_length()
     levels = num_levels(N0, 3)
-    Nc, _ = initial_width(N0, M0, 3, levels)
-    assert Nc < N0 and res.stats["initial_compact_dispatches"] == 1
+    deg = default_ell_deg(N0, M0)
+    # the root's down scan, run alone
+    root = G.pad_graph(g, N0, M0)
+    if kind == "kron":
+        lanes = jax.tree_util.tree_map(lambda a: a[None], root)
+        c, _, sizes, (pairs, ran) = jax.jit(lambda gs: _coarsen_skewed_lanes(
+            gs, levels, deg, 3))(lanes)
+        c, sizes = jax.tree_util.tree_map(lambda a: a[0], c), sizes[0]
+        twohop, ran = int(pairs.sum()), np.asarray(ran) > 0
+        # the levels that ran come first; a stall makes the rest identities
+        assert ran[0] and np.all(np.diff(ran.astype(int)) <= 0)
+    else:
+        c, _, sizes = jax.jit(lambda g0: _coarsen_down(
+            g0, levels, "ell", deg))(root)
+        twohop, ran = 0, np.ones(levels, bool)
+    Nc, Mc = initial_width(N0, M0, 3, levels, kind == "kron")
+    compact = int(c.n) <= Nc and int(c.m) <= Mc
+    assert Nc < N0 and res.stats["initial_dispatches"] == 1
+    assert res.stats["initial_compact_dispatches"] == compact
+    assert compact == (kind == "rgg")  # the kron root stalls past Nc
     passes = 1 + Preset.get("eco").vcycles
     assert res.stats["vcycle_padded_vertex_work"] == \
-        N0 * (passes + levels) - (N0 - Nc)
+        N0 * (passes + int(ran.sum())) - compact * (N0 - Nc)
+    sizes = np.asarray(sizes)
+    assert res.stats["vcycle_real_vertex_work"] == \
+        n * passes + int(sizes[ran].sum())
+    coarsest = int(sizes[-1])
+    assert res.stats["coarsest_real_vertices"] == coarsest
+    assert res.stats["coarsening_target"] == 72
+    assert res.stats["coarsen_merges"] == n - coarsest
+    assert res.stats["coarsen_twohop_merges"] == twohop
+    assert (twohop > 0) == (kind == "kron")
+
+
+@pytest.mark.parametrize("make,mode", [
+    (lambda: G.gen_rgg(1000, seed=7), "ell"),
+    (lambda: G.gen_rgg(400, seed=2), "ell"),   # rows just past the cap
+    (lambda: G.gen_grid(30), "ell"),
+    (lambda: G.gen_road(900), "ell"),
+    (lambda: G.gen_kron(9, seed=1), "skewed"),
+    (lambda: G.from_edges(300, np.zeros(200, np.int64), np.arange(1, 201)),
+     "skewed"),                                # a star, 99 isolated
+])
+def test_the_request_decides_its_down_scan(make, mode, monkeypatch,
+                                          fresh_programs):
+    """A request coarsens by the skewed down scan where more than an
+    eighth of its root's edge slots lie past the root's ELL cap, by
+    today's programs otherwise; every dispatch of it alike, on every
+    strategy that runs the planner and on the host loops."""
+    from repro.core import multisection as MS
+    seen = []
+    real = MS.batched_partition
+    monkeypatch.setattr(MS, "batched_partition",
+                        lambda *a: seen.append(a[5]) or real(*a))
+    for strategy in ("bucket", "device"):
+        seen.clear()
+        res = hierarchical_multisection(make(), H_PAPER, preset="fast",
+                                        strategy=strategy)
+        assert set(seen) == {mode}, strategy
+        assert (res.stats["coarsen_twohop_merges"] > 0) == (mode == "skewed")
+    planner = MS.LevelPlanner(make(), H_PAPER, resident=False)
+    assert planner.coarsen == mode
+
+
+def test_kron_mapping_against_the_benchmark_reference(fresh_programs):
+    """``shared_map`` on a Graph500 scale-10 graph (the benchmark's own
+    generator) on 4:8:3, checked as the chip benchmark checks it: one PE
+    in [0, k) per task, every PE's load within what the instance admits,
+    and J as reported equal to the float64 reference; the counters of
+    the skewed down scan come back with it."""
+    import sys
+    from pathlib import Path
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import yardstick as Y
+    from bench.families.kron import instances
+    from repro.core.taskgraph import TaskGraph
+    h = Hierarchy(a=(4, 8, 3), d=(1.0, 10.0, 100.0))
+    inst = instances({"scale": 10, "edgefactor": 16, "instance_seeds": [1],
+                      "initiator": {"A": 0.57, "B": 0.19, "C": 0.19}}, 0)[0]
+    tg = TaskGraph.from_edges(inst.n, inst.u, inst.v, inst.w)
+    res = shared_map(tg, h, SharedMapConfig(eps=0.03, preset="eco"))
+    pe = np.asarray(res.pe_of)
+    assert pe.shape == (inst.n,) and pe.min() >= 0 and pe.max() < h.k
+    assert Y.load_over_limit(inst, pe, h.k, 0.03) <= 1.0
+    J64 = Y.numpy_J(inst, pe, h.a, h.d)
+    assert abs(res.J - J64) <= 1e-5 * J64
+    assert J64 < 0.7 * Y.random_J(inst, h.a, h.d)
+    st = res.stats
+    assert 0 < st["coarsen_twohop_merges"] <= st["coarsen_merges"]
+    assert st["coarsest_real_vertices"] + st["coarsen_merges"] > inst.n
+    assert 0 <= st["initial_compact_dispatches"] <= st["initial_dispatches"]
+
+
+def test_load_limit_admits_the_whole_weight_ceiling():
+    """With whole vertex weights no partition beats ceil(W / k): a lane
+    whose eps leaves less than one unit above W / k gets that ceiling;
+    fractional weights keep (1 + eps) W / k."""
+    import jax.numpy as jnp
+    from repro.core.partition import load_limit
+    g = G.from_edges(174, np.arange(173), np.arange(1, 174))
+    assert float(load_limit(g, jnp.float32(0.01), 4)) == 44.0
+    assert float(load_limit(g, jnp.float32(0.03), 4)) == \
+        pytest.approx(1.03 * 174 / 4)
+    half = G.from_edges(174, np.arange(173), np.arange(1, 174),
+                        vwgt=np.full(174, 0.5))
+    assert float(load_limit(half, jnp.float32(0.01), 4)) == \
+        pytest.approx(1.01 * 87 / 4)

@@ -349,25 +349,28 @@ def test_partition_and_batched_equal_the_full_width_branch(coarsen):
     full = jax.jit(lambda gs, e, s: _partition_lanes(
         gs, e, s, k, levels, "fast", "xla", None, coarsen,
         width=(g.N, g.M)))
-    ref, _, took = full(_lanes(g), eps[None], salt)
+    ref, _, took, _ = full(_lanes(g), eps[None], salt)
     assert int(took) == 0
     np.testing.assert_array_equal(
         np.asarray(partition(g, k, eps, levels, "fast", 5, "xla", None,
                              coarsen)), np.asarray(ref[0]))
     fn = batched_partition(k, levels, "fast", "xla", None, coarsen)
-    parts, _, took = fn.with_counters(_lanes(g), eps[None], salt)
+    parts, _, took, _ = fn.with_counters(_lanes(g), eps[None], salt)
     assert int(took) == 1
     np.testing.assert_array_equal(np.asarray(parts), np.asarray(ref))
 
 
 def _no_fit_graph(shape, n):
-    """An edgeless or star graph: HEM cannot shrink it below ``n - levels``."""
+    """An edgeless graph or a perfect matching: HEM cannot shrink it below
+    ``n / 2``, and no row runs past the ELL cap, so the request keeps the
+    ELL down scan. (A star has since gone to the skewed down scan, whose
+    two-hop pairing shrinks it.)"""
     if shape == "edgeless":
         return G.from_edges(n, np.zeros(0, np.int64), np.zeros(0, np.int64))
-    return G.from_edges(n, np.zeros(n - 1, np.int64), np.arange(1, n))
+    return G.from_edges(n, np.arange(0, n, 2), np.arange(1, n, 2))
 
 
-@pytest.mark.parametrize("shape", ["edgeless", "star"])
+@pytest.mark.parametrize("shape", ["edgeless", "matching"])
 def test_initial_full_width_where_the_coarsest_graph_does_not_fit(shape):
     from repro.core.hierarchy import Hierarchy
     from repro.core.multisection import hierarchical_multisection
@@ -398,10 +401,10 @@ def test_one_lane_that_does_not_fit_sends_the_dispatch_to_full_width():
     fn = batched_partition(k, num_levels(fits.N, k), "fast", "xla", None)
     eps = jnp.full((2,), 0.03, jnp.float32)
     salts = jnp.asarray([5, 6], jnp.int32)
-    parts, _, took = fn.with_counters(_lanes(fits, wide), eps, salts)
+    parts, _, took, _ = fn.with_counters(_lanes(fits, wide), eps, salts)
     assert int(took) == 0
     for i, lane in enumerate((fits, wide)):
-        alone, _, took = fn.with_counters(_lanes(lane), eps[i:i + 1],
+        alone, _, took, _ = fn.with_counters(_lanes(lane), eps[i:i + 1],
                                           salts[i:i + 1])
         assert int(took) == (i == 0)
         np.testing.assert_array_equal(np.asarray(parts[i]),

@@ -260,3 +260,26 @@ def test_worker_mode_parent_starts_no_backend(kind):
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "OK" in proc.stdout
+
+
+def test_skewed_and_unskewed_requests_keep_their_direct_answers():
+    """A power-law graph coarsens by its own down scan (the dispatch key
+    names it), so the service never stacks it with a mesh's lanes, and
+    each request gets the answer the direct path gives."""
+    from repro.core.multisection import LevelPlanner
+    mesh = G.gen_rgg(300, seed=40)
+    # a hub over 200 leaves and a path: the mesh's shape bucket
+    u = np.concatenate([np.zeros(200, np.int64), np.arange(200, 299)])
+    v = np.concatenate([np.arange(1, 201), np.arange(201, 300)])
+    skewed = G.from_edges(300, u, v)
+    keys = [LevelPlanner(g, H, preset="fast").plan()[0].exec_key
+            for g in (mesh, skewed)]
+    assert [k[-1] for k in keys] == ["ell", "skewed"]
+    assert keys[0][:-1] == keys[1][:-1]  # one shape bucket, two programs
+    direct = [shared_map_direct(g, H, CFG) for g in (mesh, skewed)]
+    with MappingService() as s:
+        futs = [s.submit(g, H, CFG) for g in (mesh, skewed)]
+        res = [f.result(timeout=600) for f in futs]
+    for d, r in zip(direct, res):
+        assert np.array_equal(d.pe_of, r.pe_of)
+        assert d.J == r.J

@@ -67,7 +67,7 @@ def test_level_sizes_equal_the_coarsening_cascade(g):
     assert levels >= 2
     deg = G.default_ell_deg(g.N, g.M)
     fn = batched_partition(4, levels, "fast", "auto", deg)
-    parts, sizes, _ = fn.with_counters(
+    parts, sizes, _, _ = fn.with_counters(
         _stack(g, 2), jnp.full((2,), 0.03, jnp.float32),
         jnp.asarray([3, 5], jnp.int32))
     ns, _ = coarsen_cascade(g, levels, ell_deg=deg)
@@ -162,3 +162,23 @@ def test_shared_map_writes_repro_spans_of_one_request(g, tmp_path):
     plans = [dict(e.stats) for e in spans if e.name == "repro.plan"]
     assert [p["depth"] for p in plans] == [3, 2, 1]
     assert [p["lanes"] for p in plans] == [1, 3, 6]
+
+
+def test_skewed_dispatch_ops_carry_the_twohop_scope_under_coarsen():
+    """The skewed down scan's pairing runs under ``twohop`` inside
+    ``coarsen``: its ops name it, and ``bench/stages.py`` counts them in
+    ``coarsen``."""
+    import sys
+    from pathlib import Path
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.stages import stage_of
+    star = G.from_edges(300, np.zeros(200, np.int64), np.arange(1, 201))
+    fn = batched_partition(4, num_levels(star.N, 4), "fast", "xla", None,
+                           "skewed")
+    names = _op_names(jax.jit(fn.with_counters).lower(
+        _stack(star, 2), jnp.full((2,), 0.03, jnp.float32),
+        jnp.arange(2, dtype=jnp.int32)).compile())
+    twohop = [n for n in names if "twohop" in n]
+    assert twohop and {stage_of(n) for n in twohop} == {"coarsen"}
